@@ -56,9 +56,10 @@ def run(out):
     w, p, prefE = root.weight, root.data.column("p"), shred.root_prefE
     dparams = sampling.fused_draw_params(w, p, prefE)
     assert dparams is not None, "workload must be fused-capable"
+    massE = sampling.exprace_mass(w, p)
 
     def eager_pernode():
-        ps = sampling.exprace_positions(key, w, p, prefE, cap,
+        ps = sampling.exprace_positions(key, w, p, prefE, massE, cap,
                                         arrival_cap=acap)
         pos = jnp.minimum(ps.positions, jnp.maximum(prefE[-1] - 1, 0))
         return probe.get(shred, pos, rep="usr"), ps

@@ -32,15 +32,14 @@ def fraction(r):
     """Roofline fraction: useful model FLOP-time over the dominant term.
 
     Peak FLOP/s comes from ``config.PEAK_FLOPS`` keyed by the record's
-    ``backend`` field; records without one (every pre-§15 dry run) resolve
-    to the TPU row — the historical 197e12 constant — so their committed
-    ratios are unchanged."""
+    ``device_kind``; records without one are dry runs of v5e meshes
+    (``launch/dryrun.py``) and resolve to the v5e row."""
     if "roofline" not in r or "model_flops_per_device" not in r:
         return None
     dom = max(r["roofline"]["compute_s"], r["roofline"]["memory_s"],
               r["roofline"]["collective_s"])
     t_model = r["model_flops_per_device"] / config.peak_flops(
-        r.get("backend", "tpu"))
+        r.get("device_kind", "TPU v5 lite"))
     return t_model / dom if dom > 0 else None
 
 

@@ -22,9 +22,10 @@ def main() -> None:
     ap.add_argument("--tiny", action="store_true",
                     help="tiny workload sizes (CI smoke; sets REPRO_BENCH_TINY)")
     args = ap.parse_args()
+    from repro import config
+    config.enable_compile_cache()
     if args.tiny:
         # Before the suite imports: sizes are chosen at module/run scope.
-        from repro import config
         config.set_bench_tiny(True)
 
     from . import (bench_position_sampling, bench_uniform_e2e, bench_poisson,

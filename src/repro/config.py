@@ -23,11 +23,12 @@ Resolution order (first hit wins — DESIGN.md §14):
 
     per-call ``policy=``  >  ``override(...)`` context  >  environment
 
-Exact env semantics (kept bit-for-bit from the pre-consolidation readers;
-the CI matrix relies on ``REPRO_PALLAS_INTERPRET=''`` meaning *interpret*):
+Env semantics:
 
     enabled   = REPRO_PALLAS_DISABLE  in ("", "0")   (default "0")
-    interpret = REPRO_PALLAS_INTERPRET != "0"        (default "1")
+    interpret = REPRO_PALLAS_INTERPRET: "0" -> False, unset or "" -> the
+                backend default (compiled on a TPU, interpreted
+                elsewhere), anything else -> True
     prefer    = REPRO_PALLAS_PREFER   not in ("","0") (default "0")
 
 Platform setup (``xla_force_host_platform_device_count``) lives here too so
@@ -38,18 +39,23 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import dataclasses
+import functools
 import os
 
 __all__ = [
     "KernelPolicy", "policy_from_env", "current_policy", "override",
     "DEFAULT_VMEM_LIMIT", "PAGED_PACK_LIMIT", "force_host_devices",
     "bench_tiny", "set_bench_tiny", "backend", "device_kind", "backend_key",
-    "PEAK_FLOPS", "peak_flops",
+    "PEAK_FLOPS", "peak_flops", "interpret_for_backend",
+    "kernel_unsupported", "enable_compile_cache", "resolve_interpret",
+    "resolves_interpret", "COMPILE_CACHE_DIR",
 ]
 
-# int32 elements kept fully VMEM-resident (bsearch prefix tables, the
-# fused-GET arena, and the fused-draw scratch share this budget — see
-# DESIGN.md §9; ``kernels/ops.py`` re-exports it as VMEM_PREF_LIMIT).
+# int32 elements kept fully VMEM-resident (the fused-GET arena and the
+# fused-draw scratch share this budget — DESIGN.md §9; ``kernels/ops.py``
+# re-exports it as VMEM_PREF_LIMIT). Only kernels in TPU_UNLOWERABLE read
+# it today, so it bounds the CPU/interpret routes; it is to be re-derived
+# from ``memory_analysis()`` once an arena kernel lowers on the TPU.
 DEFAULT_VMEM_LIMIT = 1 << 21
 
 # Ceiling on the *total* size of a paged index arena (int32 elements,
@@ -67,8 +73,10 @@ class KernelPolicy:
     enabled     master switch: False routes every wrapper through its
                 pure-XLA/jnp fallback (the operator escape hatch for a
                 kernel bug; historical ``REPRO_PALLAS_DISABLE=1``).
-    interpret   run kernels in Pallas interpret mode (the validated mode
-                on this CPU container; False = compiled mode on real TPU).
+    interpret   run kernels in Pallas interpret mode. ``None`` (the
+                default) resolves from the backend at construction:
+                compiled on a TPU, interpreted on CPU/GPU
+                (``interpret_for_backend``).
     prefer      prefer kernels over their XLA twins inside jitted hot
                 paths even in interpret mode (the CI interpret leg pins
                 this so the whole tier-1 suite exercises the kernels).
@@ -92,12 +100,16 @@ class KernelPolicy:
     """
 
     enabled: bool = True
-    interpret: bool = True
+    interpret: bool = None
     prefer: bool = False
     vmem_limit: int = DEFAULT_VMEM_LIMIT
     fused_draw: bool = True
     tuned: bool = True
     tile_overrides: tuple = ()
+
+    def __post_init__(self):
+        if self.interpret is None:
+            object.__setattr__(self, "interpret", interpret_for_backend())
 
     @property
     def preferred(self) -> bool:
@@ -120,16 +132,24 @@ class KernelPolicy:
         return None
 
 
+def interpret_for_backend(backend_name: str = None) -> bool:
+    """The interpret-mode default for a backend: False (compiled Mosaic
+    kernels) on a TPU, True everywhere else — the kernels are written for
+    the TPU, and the interpreter is how CPU hosts run them."""
+    return (backend_name or backend()) != "tpu"
+
+
 def policy_from_env() -> KernelPolicy:
     """The default policy, parsed from the environment *at call time* (so
-    tests and CI legs can flip a var without re-importing anything). The
-    parse of each variable is exactly the historical reader's — in
-    particular ``REPRO_PALLAS_INTERPRET=''`` still means interpret=True
-    (the CI matrix sets the empty string on non-interpret legs)."""
+    tests and CI legs can flip a var without re-importing anything).
+    ``REPRO_PALLAS_INTERPRET`` unset or empty means the backend default
+    (``interpret_for_backend``); ``"0"`` forces compiled mode and any other
+    value forces the interpreter."""
     env = os.environ.get
+    interp = env("REPRO_PALLAS_INTERPRET", "")
     return KernelPolicy(
         enabled=env("REPRO_PALLAS_DISABLE", "0") in ("", "0"),
-        interpret=env("REPRO_PALLAS_INTERPRET", "1") != "0",
+        interpret=None if interp == "" else interp != "0",
         prefer=env("REPRO_PALLAS_PREFER", "0") not in ("", "0"),
     )
 
@@ -145,6 +165,30 @@ def current_policy(policy: KernelPolicy = None) -> KernelPolicy:
         return policy
     installed = _override.get()
     return installed if installed is not None else policy_from_env()
+
+
+def resolve_interpret(interpret: bool = None) -> bool:
+    """A kernel's ``interpret=`` argument: ``None`` takes the active
+    policy's mode (compiled on a TPU, interpreted elsewhere)."""
+    return current_policy().interpret if interpret is None else interpret
+
+
+def resolves_interpret(kernel):
+    """Decorate a jitted kernel entry whose static ``interpret`` argument
+    defaults to ``None``: each call resolves it (``resolve_interpret``)
+    before the jit cache key is formed, so a policy change never reuses a
+    trace built for the other mode. ``.lower`` resolves the same way."""
+    @functools.wraps(kernel)
+    def call(*args, interpret: bool = None, **kwargs):
+        return kernel(*args, interpret=resolve_interpret(interpret),
+                      **kwargs)
+
+    def lower(*args, interpret: bool = None, **kwargs):
+        return kernel.lower(*args, interpret=resolve_interpret(interpret),
+                            **kwargs)
+
+    call.lower = lower
+    return call
 
 
 @contextlib.contextmanager
@@ -165,19 +209,55 @@ def override(policy: KernelPolicy):
         _override.reset(token)
 
 
+# Kernels the TPU compiler (Mosaic) refuses: kernel -> (the compiler's
+# NotImplementedError message, where it comes from). A declared capability,
+# read when a plan binds its routes (core/probe.select_rep / select_draw),
+# never a try/except around a compile. tests/test_tpu_compile.py compiles
+# each against a described v5e and checks it still fails with exactly this
+# message, so the table cannot go stale.
+TPU_UNLOWERABLE = {
+    "tree_probe": (
+        "Only 2D gather is supported",
+        "the per-lane jnp.take from the 1-D VMEM arena in tree_walk"),
+    "tree_probe_paged": (
+        "Only 2D gather is supported",
+        "the same tree_walk gathers, per page"),
+    "fused_draw": (
+        "Unimplemented primitive in Pallas TPU lowering for KernelType.TC: "
+        "cumsum",
+        "draw_core; its per-lane 1-D gathers are the ones tree_probe "
+        "fails on"),
+    "fused_sample": (
+        "Unimplemented primitive in Pallas TPU lowering for KernelType.TC: "
+        "cumsum",
+        "draw_core, as in fused_draw"),
+}
+
+
+def kernel_unsupported(kernel: str, policy: KernelPolicy = None):
+    """Why ``kernel`` cannot run under the active policy on this backend,
+    or ``None`` when it can: compiled mode on a TPU rules out the kernels
+    in ``TPU_UNLOWERABLE``; the interpreter runs every kernel."""
+    if current_policy(policy).interpret or backend() != "tpu":
+        return None
+    if kernel not in TPU_UNLOWERABLE:
+        return None
+    message, where = TPU_UNLOWERABLE[kernel]
+    return f"NotImplementedError: {message} ({where})"
+
+
 # ---------------------------------------------------------------------------
 # Platform setup (process-level, owned here so launch scripts import one
 # module for every knob; launch/mesh.py delegates).
 # ---------------------------------------------------------------------------
 
 def force_host_devices(n: int) -> int:
-    """Ask XLA for ``n`` virtual host (CPU) devices; returns the count
-    actually available. Only effective before the backend initializes —
-    appends ``--xla_force_host_platform_device_count`` to ``XLA_FLAGS``
-    and reports (rather than raises) when the backend beat us to it, so
-    callers degrade to the real device count."""
-    import sys
-
+    """Ask XLA for ``n`` virtual host (CPU) devices; returns the device
+    count. Only effective before the backend initializes — appends
+    ``--xla_force_host_platform_device_count`` to ``XLA_FLAGS`` — so it
+    raises ``RuntimeError`` when fewer than ``n`` devices exist (the
+    backend beat it to initialization, or ``XLA_FLAGS`` was pre-set):
+    a run never silently proceeds on fewer devices than it asked for."""
     import jax
 
     if n > 1:
@@ -187,10 +267,34 @@ def force_host_devices(n: int) -> int:
             os.environ["XLA_FLAGS"] = f"{flags} {flag}".strip()
     got = len(jax.devices())
     if got < n:
-        print(f"[mesh] requested {n} host devices, backend has {got} "
-              f"(already initialized, or XLA_FLAGS pre-set); using {got}",
-              file=sys.stderr)
+        raise RuntimeError(
+            f"requested {n} devices, backend {jax.default_backend()!r} has "
+            f"{got} (already initialized, or XLA_FLAGS pre-set)")
     return got
+
+
+# Fixed, git-ignored compile-cache directory inside the checkout. JAX keys a
+# cache entry by the directory too, so the path never carries a temporary
+# name, a process id or a time.
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(
+        __file__)))), ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for this process and
+    return its directory. ``JAX_COMPILATION_CACHE_DIR``, when set, is
+    honoured as it is (JAX reads it itself; nothing else is set);
+    otherwise the cache lives at ``COMPILE_CACHE_DIR``. Call it before the
+    first compile — entry points (``chip_smoke.py``, ``benchmarks/run.py``,
+    ``launch/serve.py``, ``launch/train.py``) do so first thing."""
+    import jax
+
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR", "")
+    if env_dir:
+        return env_dir
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
 
 
 # ---------------------------------------------------------------------------
@@ -228,26 +332,29 @@ def backend_key() -> str:
     return f"{backend()}/{device_kind()}"
 
 
-# Peak dense-math FLOP/s per backend (bf16-class units), the denominator of
-# the roofline fraction (benchmarks/roofline.py). 197e12 is the documented
-# TPU default this repo has always modeled (v5e-class bf16); the GPU and
-# CPU rows are representative single-device figures (A100-class bf16
-# tensor-core peak; a ~32-core AVX-512 host), good for bottleneck
-# *classification*, not for absolute MFU claims.
+# Published per-chip peaks, keyed by ``jax.devices()[0].device_kind`` exactly
+# as JAX reports it. Source: Google Cloud documentation, "TPU v5e" (197
+# TFLOP/s bf16, 16 GB HBM at 819 GB/s per chip). A device that is not in the
+# table is an error, not a default: a roofline share against a guessed peak
+# is not a measurement.
 PEAK_FLOPS = {
-    "tpu": 197e12,
-    "gpu": 312e12,
-    "cpu": 2e12,
+    "TPU v5 lite": {"flops_bf16": 197e12, "hbm_bytes_per_s": 819e9,
+                    "source": "Google Cloud documentation, TPU v5e"},
 }
 
 
-def peak_flops(backend_name: str = None) -> float:
-    """Peak FLOP/s for ``backend_name`` (default: the detected backend).
-    Unknown names fall back to the TPU row — the historical constant, so
-    pre-existing dry-run records keep their ratios."""
-    if backend_name is None:
-        backend_name = backend()
-    return PEAK_FLOPS.get(backend_name, PEAK_FLOPS["tpu"])
+def peak_flops(kind: str = None) -> float:
+    """Peak bf16 FLOP/s of device kind ``kind`` (default: device 0 of this
+    process). Raises ``KeyError`` for a device kind the table does not
+    list."""
+    if kind is None:
+        import jax
+
+        kind = jax.devices()[0].device_kind
+    if kind not in PEAK_FLOPS:
+        raise KeyError(f"no published peak for device kind {kind!r}; "
+                       f"known: {sorted(PEAK_FLOPS)}")
+    return PEAK_FLOPS[kind]["flops_bf16"]
 
 
 # ---------------------------------------------------------------------------

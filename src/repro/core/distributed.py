@@ -30,23 +30,25 @@ production meshes.
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding
 
 from .database import Database
 from .jointree import JoinQuery, JoinTreeNode
-from .relations import Relation, dense_keys
-from .shred import Shred, build_plan, build_shred, pack_index
-from repro.compat import axis_size
+from .relations import Relation
+from .shred import (Shred, _dense_gids_np, _np_i64, build_plan, build_shred,
+                    pack_index, root_prefix)
 
 __all__ = [
     "RootPartition", "StackedShred", "ShardedPoissonSampler",
     "partition_root", "semijoin_filter", "build_stacked_shred",
     "build_stacked", "reshard_incremental", "fold_shard_key",
+    "place_stacked",
 ]
 
 I64 = jnp.int64
@@ -61,7 +63,7 @@ def fold_shard_key(key, axes: Tuple[str, ...]):
     sharded path both rely on."""
     idx = jnp.zeros((), jnp.int32)
     for a in axes:
-        idx = idx * axis_size(a) + jax.lax.axis_index(a)
+        idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return jax.random.fold_in(key, idx)
 
 
@@ -88,9 +90,10 @@ def semijoin_filter(db: Database, query: JoinQuery) -> Database:
         if parent_inst is not None:
             shared = sorted(set(parent_inst.attrs) & set(inst.attrs))
             if shared and inst.num_rows and parent_inst.num_rows:
-                kp, kc = dense_keys([parent_inst.column(v) for v in shared],
-                                    [inst.column(v) for v in shared])
-                mask = np.asarray(jnp.isin(kc, kp))
+                kp, kc = _dense_gids_np(
+                    [_np_i64(parent_inst.column(v)) for v in shared],
+                    [_np_i64(inst.column(v)) for v in shared])
+                mask = np.isin(kc, kp)
             else:  # cross product (or empty side): nothing to prune
                 mask = np.ones((inst.num_rows,), bool)
             name = tnode.atom.relation
@@ -192,9 +195,9 @@ def _build_one_shard(sdb: Database, query: JoinQuery, rep: str,
     sh = build_shred(sdb, query, rep=rep)
     n = sh.root.num_rows
     if valid < n:
-        w = jnp.where(jnp.arange(n) < valid, sh.root.weight, 0)
-        root = dataclasses.replace(sh.root, weight=w)
-        prefE = jnp.concatenate([jnp.zeros((1,), I64), jnp.cumsum(w)])
+        w = np.where(np.arange(n) < valid, np.asarray(sh.root.weight), 0)
+        root = dataclasses.replace(sh.root, weight=jnp.asarray(w))
+        prefE = root_prefix(w)
         # Re-pack the fused-GET arena: it embeds root_prefE (DESIGN.md §4).
         packed, paged = pack_index(root, prefE)
         sh = Shred(root=root, root_prefE=prefE, rep=sh.rep,
@@ -228,6 +231,18 @@ def _stack_shards(built, part: RootPartition, query: JoinQuery,
         root_name=part.root_name, valid=part.valid,
         join_sizes=tuple(int(b.root_prefE[-1]) for b in built),
     )
+
+
+def place_stacked(stacked: StackedShred, sharding) -> StackedShred:
+    """``stacked`` with every leaf of its index, weights, probabilities and
+    prefixes placed under ``sharding`` — a ``NamedSharding`` over the
+    shard axes puts shard s on the mesh's device s. Leaves already placed
+    that way are not copied."""
+    put = partial(jax.device_put, device=sharding)
+    return dataclasses.replace(
+        stacked, shred=jax.tree.map(put, stacked.shred),
+        w=put(stacked.w), p=None if stacked.p is None else put(stacked.p),
+        prefE=put(stacked.prefE))
 
 
 def build_stacked(
@@ -289,6 +304,8 @@ def reshard_incremental(
     filtered children rebuild the affected shards — bit-identical to a
     from-scratch ``build_stacked`` either way.
 
+    The new stack keeps the old one's placement (``place_stacked``).
+
     Returns ``(stacked_new, base_new, shards_reused, shards_rebuilt)``.
     """
     base_new = semijoin_filter(db_new, query)
@@ -327,8 +344,10 @@ def reshard_incremental(
             reused += 1
         else:
             built.append(_build_one_shard(sdb, query, rep, part_new.valid[s]))
-    return (_stack_shards(built, part_new, query, num_shards), base_new,
-            reused, num_shards - reused)
+    stacked_new = _stack_shards(built, part_new, query, num_shards)
+    if isinstance(stacked.w.sharding, NamedSharding):
+        stacked_new = place_stacked(stacked_new, stacked.w.sharding)
+    return stacked_new, base_new, reused, num_shards - reused
 
 
 class ShardedPoissonSampler:

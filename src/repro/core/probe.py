@@ -71,10 +71,30 @@ def _root_locate(shred: Shred, pos: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarr
                                     pos.astype(jnp.int32)),
             n - 1)
     else:
-        j = jnp.clip(jnp.searchsorted(prefE, pos, side="right") - 1, 0,
+        j = jnp.clip(ops.searchsorted(prefE, pos) - 1, 0,
                      max(n - 1, 0))
     local = pos - prefE[j]
     return j.astype(jnp.int32), local.astype(I64)
+
+
+def _mixed_radix_split(local: jnp.ndarray, w: jnp.ndarray):
+    """``(local % w, local // w)`` for int64 ``local`` and ``w >= 1``, exact
+    for |local| < 2^53 (the engine's join-size bound, ``shred.MAX_JOIN``).
+
+    Integer division has no TPU instruction: the compiler expands an int64
+    ``//``/``%`` pair into a program that takes it about half a minute to
+    build. Here a float64 quotient is only the estimate — the TPU emulates
+    float64, so its rounding is not relied on — refined once from the
+    (small) integer remainder, then corrected by one either way; every
+    step after the estimate is exact integer arithmetic, and the build
+    takes under a second.
+    """
+    wf = w.astype(jnp.float64)
+    q = jnp.floor(local.astype(jnp.float64) / wf).astype(I64)
+    q = q + jnp.floor((local - q * w).astype(jnp.float64) / wf).astype(I64)
+    r = local - q * w
+    q = jnp.where(r < 0, q - 1, jnp.where(r >= w, q + 1, q))
+    return local - q * w, q
 
 
 # ---------------------------------------------------------------------------
@@ -93,7 +113,7 @@ def _usr_child_locate(node: ShredNode, ci: int, rows: jnp.ndarray, idx: jnp.ndar
     target = base + idx
     # smallest jj with cumw_incl[jj] > target  <=>  cumw_excl[jj+1] > target
     jj = jnp.clip(
-        jnp.searchsorted(cumw_excl, target, side="right") - 1,
+        ops.searchsorted(cumw_excl, target) - 1,
         0,
         child.num_rows - 1,
     )
@@ -107,9 +127,7 @@ def _usr_sub(node: ShredNode, rows: jnp.ndarray, local: jnp.ndarray, out: Dict[s
     # Mixed-radix split (paper eq. 6-7): child 0 is least significant.
     for ci, child in enumerate(node.children):
         w = node.child_w[ci][rows]
-        w_safe = jnp.maximum(w, 1)
-        idx = local % w_safe
-        local = local // w_safe
+        idx, local = _mixed_radix_split(local, jnp.maximum(w, 1))
         crows, clocal = _usr_child_locate(node, ci, rows, idx)
         _usr_sub(child, crows, clocal, out)
 
@@ -136,7 +154,8 @@ def fused_available(shred: Shred, policy=None) -> bool:
     pol = config.current_policy(policy)
     return (shred.packed is not None
             and shred.packed.layout.size <= pol.vmem_limit
-            and pol.enabled)
+            and pol.enabled
+            and config.kernel_unsupported("tree_probe", pol) is None)
 
 
 def paged_view(shred: Shred):
@@ -158,7 +177,8 @@ def paged_available(shred: Shred, policy=None) -> bool:
     Sits strictly between ``fused`` and the per-node fallback in the
     ladder — ``fused_available`` wins when both hold."""
     pol = config.current_policy(policy)
-    if not pol.enabled or fused_available(shred, pol):
+    if (not pol.enabled or fused_available(shred, pol)
+            or config.kernel_unsupported("tree_probe_paged", pol)):
         return False
     layout = (shred.paged.layout if shred.paged is not None
               else shred.packed.layout if shred.packed is not None else None)
@@ -188,6 +208,16 @@ def select_rep(shred: Shred, base: str, policy=None) -> Tuple[str, bool]:
     return base, narrow
 
 
+def _require(kernel: str, policy=None) -> None:
+    """Raise when an explicitly requested kernel cannot run here (compiled
+    mode on a TPU, ``config.TPU_UNLOWERABLE``), naming the compiler's
+    reason — an explicit request never silently takes another route."""
+    reason = config.kernel_unsupported(kernel, policy)
+    if reason:
+        raise NotImplementedError(
+            f"kernel {kernel!r} does not compile for this backend: {reason}")
+
+
 def usr_get_rows_fused(shred: Shred, pos: jnp.ndarray) -> Dict[str, jnp.ndarray]:
     """Resolve probe positions to per-node row indices in ONE kernel launch.
 
@@ -205,6 +235,7 @@ def usr_get_rows_fused(shred: Shred, pos: jnp.ndarray) -> Dict[str, jnp.ndarray]
     guarantees join_size < 2^31 and callers clamp pads to n - 1 (GET's
     out-of-range lanes are arbitrary-but-masked either way, §4).
     """
+    _require("tree_probe")
     if not fused_available(shred):
         if paged_available(shred):
             return usr_get_rows_paged(shred, pos)
@@ -228,6 +259,7 @@ def usr_get_rows_paged(shred: Shred, pos: jnp.ndarray) -> Dict[str, jnp.ndarray]
     reach it through ``select_rep``/``get_rows`` (rep ``usr_paged``) or the
     fused ladder's fallback; it assumes ``paged_available`` held at
     selection time."""
+    _require("tree_probe_paged")
     pv = paged_view(shred)
     k = pos.shape[0]
     tiles = ops.to_tiles(pos.astype(jnp.int32))
@@ -304,6 +336,7 @@ def select_draw(shred: Shred, dparams, *, method: str, n: int = 0,
     if kernels == "pernode":
         return "pernode"
     if kernels == "fused":
+        _require("fused_draw", pol)
         if not (capable and pol.enabled):
             raise ValueError(
                 "kernels='fused' requested but the fused draw is "
@@ -312,6 +345,8 @@ def select_draw(shred: Shred, dparams, *, method: str, n: int = 0,
                 "method, and kernels enabled)")
         return "fused"
     if kernels == "paged":
+        _require("fused_sample", pol)
+        _require("tree_probe_paged", pol)
         if not (paged_capable and pol.enabled):
             raise ValueError(
                 "kernels='paged' requested but the paged draw is "
@@ -330,9 +365,10 @@ def select_draw(shred: Shred, dparams, *, method: str, n: int = 0,
     if kernels != "auto":
         raise ValueError(f"unknown kernels request {kernels!r}")
     if pol.enabled and pol.fused_draw and pol.preferred:
-        if capable:
+        if capable and not config.kernel_unsupported("fused_draw", pol):
             return "fused"
-        if paged_capable:
+        if paged_capable and not config.kernel_unsupported("fused_sample",
+                                                           pol):
             return "paged"
     return "pernode"
 
@@ -427,9 +463,7 @@ def _csr_sub(node: ShredNode, rows: jnp.ndarray, local: jnp.ndarray, out: Dict[s
     out[node.name] = rows
     for ci, child in enumerate(node.children):
         w = node.child_w[ci][rows]
-        w_safe = jnp.maximum(w, 1)
-        idx = local % w_safe
-        local = local // w_safe
+        idx, local = _mixed_radix_split(local, jnp.maximum(w, 1))
         hd = node.child_hd[ci][rows]
         crows, clocal = _csr_walk(child.weight, child.nxt, hd, idx)
         crows = jnp.maximum(crows, 0).astype(jnp.int32)  # clamp sentinel lanes
@@ -496,9 +530,7 @@ def csr_get_rows_cached(shred: Shred, pos: jnp.ndarray) -> Dict[str, jnp.ndarray
         out[node.name] = rows
         for ci, child in enumerate(node.children):
             w = node.child_w[ci][rows]
-            w_safe = jnp.maximum(w, 1)
-            idx = local % w_safe
-            local_next = local // w_safe
+            idx, local_next = _mixed_radix_split(local, jnp.maximum(w, 1))
             hd = node.child_hd[ci][rows]
             crows, clocal = _csr_walk_cached(child.weight, child.nxt, hd, idx)
             crows = jnp.maximum(crows, 0).astype(jnp.int32)

@@ -61,6 +61,7 @@ __all__ = [
     "binom_positions",
     "hybrid_positions",
     "exprace_positions",
+    "exprace_mass",
     "pt_bern_flat_positions",
     "fused_draw_params",
     "pt_positions_host",
@@ -127,7 +128,7 @@ def geo_positions(key, p, n, cap: int) -> PositionSample:
     gaps = jnp.where(p <= 0.0, jnp.asarray(n, F64) + 1.0, gaps)
     gaps = jnp.where(p >= 1.0, 0.0, gaps)
     gaps = jnp.minimum(gaps, 4.0 * jnp.asarray(n, F64) + 2.0)  # avoid inf->int UB
-    positions = jnp.cumsum(gaps.astype(I64)) + jnp.arange(cap, dtype=I64)
+    positions = ops.blocked_cumsum(gaps.astype(I64)) + jnp.arange(cap, dtype=I64)
     valid = positions < n
     # If the last lane is still in range the process hasn't exhausted [0, n):
     more = jnp.logical_and(cap > 0, valid[-1] if cap > 0 else False)
@@ -182,8 +183,36 @@ def _locate_prefix(prefE, q, hi, narrow: bool):
     return jnp.minimum(ops.searchsorted_prefix(prefE, q), hi).astype(I64)
 
 
+def _exprace_rates(p, xp=jnp):
+    """(complement flags, per-cell Poisson rates) of EXPRACE's thinning, in
+    array module ``xp`` (jnp inside the draw, numpy for the host tables)."""
+    p = xp.clip(xp.asarray(p, dtype=xp.float64), 0.0, 1.0)
+    comp = p > 0.5                      # sample failures instead of successes
+    pi = xp.where(comp, 1.0 - p, p)     # process probability, <= 1/2
+    lam = -xp.log1p(-xp.minimum(pi, 0.5))  # rate per cell, <= ln 2
+    return comp, lam
+
+
+def _exclusive_cumsum_np(x) -> np.ndarray:
+    """Exclusive prefix sum over the last axis (leading zero), numpy."""
+    zero = np.zeros(x.shape[:-1] + (1,), x.dtype)
+    return np.concatenate([zero, np.cumsum(x, axis=-1)], axis=-1)
+
+
+def exprace_mass(w, p) -> np.ndarray:
+    """EXPRACE's cumulative arrival mass: the (..., R+1) f64 exclusive
+    prefix of ``w_t * lam_t`` over the last axis, computed on the host in
+    numpy. It depends only on the plan-bound (w, p), so plans compute it
+    once per bind and pass it to every draw (``exprace_positions``'
+    ``massE``): a float64 prefix sum takes the TPU compiler minutes (it
+    emulates f64), and every draw program would otherwise carry one."""
+    _, lam = _exprace_rates(np.asarray(p), xp=np)
+    return _exclusive_cumsum_np(np.asarray(w, np.float64) * lam)
+
+
 def exprace_positions(
-    key, w, p, prefE, cap: int, arrival_cap: int = 0, narrow: bool = False
+    key, w, p, prefE, massE, cap: int, arrival_cap: int = 0,
+    narrow: bool = False,
 ) -> PositionSample:
     """EXPRACE: exact non-uniform Poisson sample positions via a thinned
     Poisson process (module docstring). Fully vectorized, exact for all
@@ -193,6 +222,7 @@ def exprace_positions(
     w:     (R,) int64   flatten weight of each root tuple (0 = dangling)
     p:     (R,) float   sampling probability of each root tuple (t[y])
     prefE: (R+1,) int64 exclusive prefix of w; prefE[-1] = join size n
+    massE: (R+1,) f64   ``exprace_mass(w, p)``, the cumulative arrival mass
     cap:        output position capacity
     arrival_cap: scratch capacity for raw Poisson arrivals (default: cap;
         needs >= ln2/min(p,1-p)-adjusted slack — see estimate.plan_capacity)
@@ -204,14 +234,9 @@ def exprace_positions(
     R = w.shape[0]
     n = prefE[-1]
     kM, kV = jax.random.split(key)
-    p = jnp.clip(p.astype(F64), 0.0, 1.0)
-    comp = p > 0.5                      # sample failures instead of successes
-    pi = jnp.where(comp, 1.0 - p, p)    # process probability, <= 1/2
-    lam = -jnp.log1p(-jnp.minimum(pi, 0.5))  # rate per cell, <= ln 2
-    wF = w.astype(F64)
+    comp, lam = _exprace_rates(p)
 
     # --- Poisson arrivals over the piecewise-constant-rate line ------------
-    massE = jnp.concatenate([jnp.zeros((1,), F64), jnp.cumsum(wF * lam)])
     Lam = massE[-1]
     M = jax.random.poisson(kM, Lam).astype(I64)
     aM = jnp.minimum(M, acap)
@@ -225,29 +250,35 @@ def exprace_positions(
     gid = jnp.where(avalid, prefE[r] + cell, n)  # global cell id; pads -> n
 
     # --- dedupe cells (>=1 arrival == one success/failure) -----------------
-    gid = jnp.sort(gid)
+    gid = jnp.sort(gid, stable=False)  # plain keys: stability changes nothing
     uniq = jnp.logical_and(
         gid < n, jnp.concatenate([jnp.ones((1,), jnp.bool_), gid[1:] != gid[:-1]])
     )
     seg = _locate_prefix(prefE, gid, R - 1, narrow)
-    hits = jnp.zeros((R,), I64).at[seg].add(uniq.astype(I64))  # per-root count
+    # Per-root count, accumulated in int32 (exact: at most acap arrivals);
+    # an int64 scatter-add is several times slower on the TPU.
+    hits = jnp.zeros((R,), jnp.int32).at[seg].add(
+        uniq.astype(jnp.int32)).astype(I64)
     k_r = jnp.where(comp, w - hits, hits)  # success count per root (exact)
-    outE = jnp.concatenate([jnp.zeros((1,), I64), jnp.cumsum(k_r)])
+    outE = jnp.concatenate([jnp.zeros((1,), I64), ops.blocked_cumsum(k_r)])
     K = outE[-1]
 
     # --- compact the unique cells, in (segment, cell) order ----------------
-    urank = jnp.cumsum(uniq.astype(I64)) - 1          # global unique rank
-    hitsE = jnp.concatenate([jnp.zeros((1,), I64), jnp.cumsum(hits)])
+    ucum = ops.blocked_cumsum(uniq.astype(I64))       # inclusive unique count
+    urank = ucum - 1                                  # global unique rank
+    hitsE = jnp.concatenate([jnp.zeros((1,), I64), ops.blocked_cumsum(hits)])
     local = gid - prefE[seg]                          # cell offset in segment
     BIGPAD = jnp.iinfo(jnp.int64).max
-    Fc = jnp.full((acap,), BIGPAD, I64)               # compacted cells
-    Gc = jnp.full((acap,), BIGPAD, I64)               # f_i - i + segment offset
-    tgt = jnp.where(uniq, urank, acap)                # dups scatter OOB (drop)
-    offE = jnp.concatenate([jnp.zeros((1,), I64), jnp.cumsum(w + 1)])
+    offE = jnp.concatenate([jnp.zeros((1,), I64), ops.blocked_cumsum(w + 1)])
     lrank = urank - hitsE[seg]                        # rank within segment
     g_val = local - lrank + offE[seg]                 # globally nondecreasing
-    Fc = Fc.at[tgt].set(jnp.where(uniq, local, BIGPAD), mode="drop")
-    Gc = Gc.at[tgt].set(jnp.where(uniq, g_val, BIGPAD), mode="drop")
+    # Unique cell i sits where ucum first exceeds i: compaction by search
+    # and gather (a scatter of int64 values is slow on the TPU).
+    slot = jnp.arange(acap, dtype=I64)
+    src = jnp.minimum(ops.searchsorted(ucum, slot), acap - 1)
+    live = slot < ucum[-1]
+    Fc = jnp.where(live, local[src], BIGPAD)          # compacted cells
+    Gc = jnp.where(live, g_val[src], BIGPAD)          # f_i - i + segment offset
 
     # --- emit output slots --------------------------------------------------
     t = jnp.arange(cap, dtype=I64)
@@ -258,7 +289,7 @@ def exprace_positions(
     direct_pos = Fc[jnp.clip(hitsE[rO] + l, 0, acap - 1)]
     # complement: l-th missing value among the segment's failures
     q = l + offE[rO]
-    c = jnp.searchsorted(Gc, q, side="right") - hitsE[rO]
+    c = ops.searchsorted(Gc, q) - hitsE[rO]
     comp_pos = l + jnp.clip(c, 0, jnp.maximum(w[rO] - 1, 0) - l + 1)
     local_out = jnp.where(comp[rO], comp_pos, direct_pos)
     positions = prefE[rO] + jnp.clip(local_out, 0, jnp.maximum(w[rO] - 1, 0))
@@ -273,37 +304,31 @@ def fused_draw_params(w, p, prefE):
     root prefixes, precomputed once per shred bind so the kernel sees only
     VMEM-ready arrays.
 
-    Called *eagerly* on concrete arrays (engine/plan._bind_shred). The
-    float tables are accumulated in f64 and cast to f32 — the fused route
-    is a float32 sampler end to end (TPU-native; the F64 multi-launch path
-    stays the precision arbiter, module docstring). Returns ``None`` when
-    the int32 narrowing cannot be certified (join + R beyond int32, or an
-    empty join) — one more rung of the static fallback ladder.
+    Computed on the host in numpy (engine/plan._bind_shred) and returned as
+    numpy arrays. The float tables are accumulated in f64 and cast to f32 —
+    the fused route is a float32 sampler end to end (TPU-native; the F64
+    multi-launch path stays the precision arbiter, module docstring).
+    Returns ``None`` when the int32 narrowing cannot be certified (join + R
+    beyond int32, or an empty join) — one more rung of the static fallback
+    ladder.
     """
+    w = np.asarray(w, np.int64)
+    prefE = np.asarray(prefE, np.int64)
     R = int(w.shape[0])
     n = int(prefE[-1])
     # offE[-1] = n + R must fit the int32 complement offsets.
     if n <= 0 or n + R >= (1 << 31) - 1:
         return None
-    p64 = jnp.clip(jnp.asarray(p, F64), 0.0, 1.0)
-    comp = p64 > 0.5                     # sample failures instead (EXPRACE)
-    pi = jnp.where(comp, 1.0 - p64, p64)
-    lam = -jnp.log1p(-jnp.minimum(pi, 0.5))
-    wF = jnp.asarray(w, F64)
-    zero1 = jnp.zeros((1,), F64)
-    massE = jnp.concatenate([zero1, jnp.cumsum(wF * lam)])
-    izero1 = jnp.zeros((1,), I64)
-    cwE = jnp.concatenate([izero1, jnp.cumsum(jnp.where(comp, w, 0))])
-    offE = jnp.concatenate([izero1, jnp.cumsum(w + 1)])
+    comp, lam = _exprace_rates(np.asarray(p), xp=np)
     return {
-        "massE": massE.astype(jnp.float32),
-        "lam": lam.astype(jnp.float32),
-        "sign": jnp.where(comp, -1, 1).astype(jnp.int32),
-        "w32": jnp.asarray(w).astype(jnp.int32),
-        "prefE32": jnp.asarray(prefE).astype(jnp.int32),
-        "cwE": cwE.astype(jnp.int32),
-        "offE": offE.astype(jnp.int32),
-        "p32": p64.astype(jnp.float32),
+        "massE": exprace_mass(w, p).astype(np.float32),
+        "lam": lam.astype(np.float32),
+        "sign": np.where(comp, -1, 1).astype(np.int32),
+        "w32": w.astype(np.int32),
+        "prefE32": prefE.astype(np.int32),
+        "cwE": _exclusive_cumsum_np(np.where(comp, w, 0)).astype(np.int32),
+        "offE": _exclusive_cumsum_np(w + 1).astype(np.int32),
+        "p32": np.clip(np.asarray(p, np.float64), 0.0, 1.0).astype(np.float32),
     }
 
 

@@ -38,15 +38,20 @@ from repro.kernels import ops as _kops
 
 from .database import Database
 from .jointree import Atom, JoinQuery, JoinTreeNode, gyo_join_tree, reroot_for
-from .relations import Relation, dense_keys
+from .relations import Relation
 
 __all__ = ["ShredNode", "Shred", "build_shred", "build_plan",
            "reshred_incremental", "PackedShred", "PagedArena", "ArenaLayout",
-           "ArenaEdge", "pack_arena", "pack_index"]
+           "ArenaEdge", "pack_arena", "pack_index", "root_prefix",
+           "MAX_JOIN"]
 
 I64 = jnp.int64
 I32 = jnp.int32
 _I32_MAX = (1 << 31) - 1
+# Largest supported join size: GET splits flat offsets exactly through
+# float64 (probe._mixed_radix_split), and EXPRACE places arrivals on cells
+# through a float64 mass line.
+MAX_JOIN = 1 << 53
 
 
 @jax.tree_util.register_pytree_node_class
@@ -376,7 +381,7 @@ def _edge_join_vars(parent_vars: Sequence[str],
 
 
 def _edge_keys(parent_rel: Relation, parent_vars: Tuple[str, ...],
-               child: ShredNode) -> Tuple[jnp.ndarray, jnp.ndarray]:
+               child: ShredNode) -> Tuple[np.ndarray, np.ndarray]:
     """Pass 1 — edge keys: one dense int64 join key per parent / child row.
 
     A keyless edge (disjoint atoms, i.e. a cross product) maps every row to
@@ -384,50 +389,55 @@ def _edge_keys(parent_rel: Relation, parent_vars: Tuple[str, ...],
     passes and both GETs handle uniformly (see jointree._gyo_parents)."""
     join_vars = _edge_join_vars(parent_vars, child.variables)
     if join_vars:
-        return dense_keys(
-            [parent_rel.column(v) for v in join_vars],
-            [child.data.column(v) for v in join_vars],
+        return _dense_gids_np(
+            [_np_i64(parent_rel.column(v)) for v in join_vars],
+            [_np_i64(child.data.column(v)) for v in join_vars],
         )
-    return (jnp.zeros((parent_rel.num_rows,), I64),
-            jnp.zeros((child.num_rows,), I64))
+    return (np.zeros((parent_rel.num_rows,), np.int64),
+            np.zeros((child.num_rows,), np.int64))
 
 
-def _sorted_group(kc: jnp.ndarray, weight: jnp.ndarray):
+def _sorted_group(kc: np.ndarray, weight: np.ndarray):
     """Pass 2 — sorted grouping: stable-sort the child by join key and
     prefix-sum its weights. ``order`` is sorted position -> row id; ties
     keep physical row order (the canonical flatten order depends on it)."""
-    order = jnp.argsort(kc, stable=True).astype(I32)
+    order = np.argsort(kc, kind="stable").astype(np.int32)
     kc_sorted = kc[order]
-    w_sorted = weight[order]
-    cumw_excl = jnp.concatenate([jnp.zeros((1,), I64), jnp.cumsum(w_sorted)])
+    cumw_excl = np.concatenate(
+        [np.zeros((1,), np.int64), np.cumsum(weight[order], dtype=np.int64)])
     return order, kc_sorted, cumw_excl
 
 
-def _link_columns(kp: jnp.ndarray, kc_sorted: jnp.ndarray,
-                  order: jnp.ndarray, cumw_excl: jnp.ndarray, rep: str):
+def _link_columns(kp: np.ndarray, kc_sorted: np.ndarray,
+                  order: np.ndarray, cumw_excl: np.ndarray, rep: str):
     """Pass 3 — link columns: each parent row's run boundaries in the sorted
     child (USR) and the chained successor lists (CSR)."""
     n = order.shape[0]
-    s = jnp.searchsorted(kc_sorted, kp, side="left")
-    e = jnp.searchsorted(kc_sorted, kp, side="right")
-    child_len = (e - s).astype(I32)
+    # Edge keys are dense ranks (``_edge_keys``), so each key's run in the
+    # sorted child comes from a histogram: O(n), where a binary search per
+    # parent row misses the cache at every step.
+    g = int(max(kp.max(initial=0), kc_sorted.max(initial=0))) + 1
+    counts = np.bincount(kc_sorted, minlength=g)
+    s = (np.cumsum(counts) - counts)[kp]
+    e = s + counts[kp]
+    child_len = (e - s).astype(np.int32)
     child_w = cumw_excl[e] - cumw_excl[s]
-    child_start = s.astype(I64)
+    child_start = s.astype(np.int64)
     # CSR head: first row (in sorted order) of the run; -1 when the run is empty.
     if n == 0:
-        child_hd = jnp.full((kp.shape[0],), -1, I32)
+        child_hd = np.full((kp.shape[0],), -1, np.int32)
     else:
-        child_hd = jnp.where(e > s, order[jnp.minimum(s, n - 1)], -1).astype(I32)
+        child_hd = np.where(e > s, order[np.minimum(s, n - 1)],
+                            -1).astype(np.int32)
 
     nxt = None
     if rep in ("csr", "both"):
         # nxt[row] = successor row in the same-key sorted run, else -1.
-        same_next = jnp.concatenate(
-            [kc_sorted[1:] == kc_sorted[:-1], jnp.zeros((1,), jnp.bool_)]
-        )
-        succ = jnp.concatenate([order[1:], jnp.full((1,), -1, I32)])
-        nxt_sorted = jnp.where(same_next, succ, -1).astype(I32)
-        nxt = jnp.zeros((n,), I32).at[order].set(nxt_sorted)
+        same_next = np.concatenate(
+            [kc_sorted[1:] == kc_sorted[:-1], np.zeros((1,), np.bool_)])
+        succ = np.concatenate([order[1:], np.full((1,), -1, np.int32)])
+        nxt = np.zeros((n,), np.int32)
+        nxt[order] = np.where(same_next, succ, -1)
     return child_hd, child_start, child_len, child_w, nxt
 
 
@@ -435,6 +445,7 @@ def _group_child(
     parent_rel: Relation,
     parent_vars: Tuple[str, ...],
     child: ShredNode,
+    child_weight: np.ndarray,
     rep: str,
 ):
     """Group the child by the shared join key; compute the parent's link
@@ -443,7 +454,7 @@ def _group_child(
     of the three reusable passes ``reshred_incremental`` also merges into
     (DESIGN.md §11)."""
     kp, kc = _edge_keys(parent_rel, parent_vars, child)
-    order, kc_sorted, cumw_excl = _sorted_group(kc, child.weight)
+    order, kc_sorted, cumw_excl = _sorted_group(kc, child_weight)
     child_hd, child_start, child_len, child_w, nxt = _link_columns(
         kp, kc_sorted, order, cumw_excl, rep)
     return child_hd, child_start, child_len, child_w, nxt, order, cumw_excl
@@ -451,44 +462,63 @@ def _group_child(
 
 def _build_node(
     tnode: JoinTreeNode, db: Database, rep: str, owned_above: frozenset
-) -> ShredNode:
+) -> Tuple[ShredNode, np.ndarray]:
+    """Build one node and its subtree; returns the node (device arrays)
+    and its weight column on the host, which the parent's grouping needs.
+
+    The grouping passes run on the host in numpy: they are exact integer
+    sorts and scans, and as eager device ops every index size would be a
+    fresh TPU compile of a multi-million-element sort (minutes each). The
+    finished columns are placed on the default device."""
     rel = db.instance_for(tnode.atom)
     rel.validate()
     variables = tuple(tnode.atom.variables)
     owned = tuple(v for v in dict.fromkeys(variables) if v not in owned_above)
     below = owned_above | set(variables)
 
-    children: List[ShredNode] = []
-    for c in tnode.children:
-        children.append(_build_node(c, db, rep, below))
+    children = [_build_node(c, db, rep, below) for c in tnode.children]
 
     n = rel.num_rows
-    weight = jnp.ones((n,), I64)
+    weight = np.ones((n,), np.int64)
     hds, starts, lens, ws = [], [], [], []
     new_children = []
-    for child in children:
-        hd, st, ln, w, nxt, perm, cume = _group_child(rel, variables, child, rep)
-        hds.append(hd)
-        starts.append(st)
-        lens.append(ln)
-        ws.append(w)
-        new_children.append(
-            dataclasses.replace(child, nxt=nxt, perm=perm, cumw_excl=cume)
-        )
+    for child, child_weight in children:
+        hd, st, ln, w, nxt, perm, cume = _group_child(
+            rel, variables, child, child_weight, rep)
+        hds.append(jnp.asarray(hd))
+        starts.append(jnp.asarray(st))
+        lens.append(jnp.asarray(ln))
+        ws.append(jnp.asarray(w))
+        new_children.append(dataclasses.replace(
+            child, nxt=None if nxt is None else jnp.asarray(nxt),
+            perm=jnp.asarray(perm), cumw_excl=jnp.asarray(cume)))
         weight = weight * w  # zero-weight propagation == semijoin reduction
 
-    return ShredNode(
+    node = ShredNode(
         name=tnode.atom.name,
         variables=variables,
         owned=owned,
         data=rel.project(tuple(dict.fromkeys(variables))),
-        weight=weight,
+        weight=jnp.asarray(weight),
         children=tuple(new_children),
         child_hd=tuple(hds),
         child_start=tuple(starts),
         child_len=tuple(lens),
         child_w=tuple(ws),
     )
+    return node, weight
+
+
+def root_prefix(weight) -> jnp.ndarray:
+    """The root's (R+1,) int64 exclusive weight prefix, summed on the host;
+    ``prefix[-1]`` is the join size. Raises beyond ``MAX_JOIN``."""
+    w = np.asarray(weight, np.int64)
+    total = int(w.sum(dtype=np.uint64)) if w.size else 0
+    if total >= MAX_JOIN:
+        raise ValueError(f"join size {total} is beyond the supported "
+                         f"2^53 (shred.MAX_JOIN)")
+    return jnp.asarray(np.concatenate(
+        [np.zeros((1,), np.int64), np.cumsum(w, dtype=np.int64)]))
 
 
 def build_shred(db: Database, query: JoinQuery, rep: str = "usr") -> Shred:
@@ -501,8 +531,8 @@ def build_shred(db: Database, query: JoinQuery, rep: str = "usr") -> Shred:
     if rep not in ("csr", "usr", "both"):
         raise ValueError(f"rep must be csr|usr|both, got {rep!r}")
     plan = build_plan(query)
-    root = _build_node(plan, db, rep, frozenset())
-    prefE = jnp.concatenate([jnp.zeros((1,), I64), jnp.cumsum(root.weight)])
+    root, weight = _build_node(plan, db, rep, frozenset())
+    prefE = root_prefix(weight)
     packed, paged = pack_index(root, prefE)
     return Shred(root=root, root_prefE=prefE, rep=rep,
                  packed=packed, paged=paged)
@@ -562,9 +592,10 @@ def _lex_scalar_keys(sorted_cols: List[np.ndarray],
 
 def _dense_gids_np(sorted_cols: List[np.ndarray],
                    query_cols: List[np.ndarray]):
-    """numpy mirror of ``relations.dense_keys`` for the rare multi-column
-    edges whose raw value ranges overflow packing: rank the union of key
-    tuples. O((n+m) log (n+m)) — the overflow fallback, not the fast path."""
+    """numpy mirror of ``relations.dense_keys``: rank the union of key
+    tuples. The host build's edge keys (``_edge_keys``), and the
+    incremental merge's fallback for multi-column edges whose raw value
+    ranges overflow packing. O((n+m) log (n+m))."""
     n = sorted_cols[0].shape[0]
     cols = [np.concatenate([s, q]) for s, q in zip(sorted_cols, query_cols)]
     order = np.lexsort(tuple(cols))
@@ -864,8 +895,7 @@ def reshred_incremental(base: Shred, db: Database, query: JoinQuery,
     if root is base.root:
         return base
     if rows_changed or weight_changed:
-        prefE = jnp.concatenate(
-            [jnp.zeros((1,), I64), jnp.cumsum(root.weight)])
+        prefE = root_prefix(root.weight)
     else:
         prefE = base.root_prefE
     # The fused-GET arena is re-packed from the merged arrays (a flat

@@ -34,10 +34,12 @@ import dataclasses
 from typing import Dict, Optional, Tuple, Union
 
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import config
 from repro.core.database import Database
 from repro.core.distributed import (
-    StackedShred, build_stacked, reshard_incremental,
+    StackedShred, build_stacked, place_stacked, reshard_incremental,
 )
 from repro.core.jointree import JoinQuery
 from repro.core.poisson import JoinSample
@@ -50,7 +52,7 @@ from .fingerprint import (
     sharded_executor_key, sharded_plan_key,
 )
 from .plan import CompiledPlan
-from .sharding import ShardedPlan, plan_shards
+from .sharding import ShardPlan, ShardedPlan, auto_mesh, plan_shards
 from .spec import DrawSpec, merge_spec
 
 __all__ = ["QueryEngine", "CacheStats"]
@@ -163,9 +165,11 @@ class QueryEngine:
         return shred
 
     def _stacked_shred_for(self, query: JoinQuery, rep: str, mesh,
-                           num_shards: int) -> StackedShred:
+                           sp: ShardPlan) -> StackedShred:
         """The stacked per-shard index for a sharded plan; lives in the same
-        LRU as single-device shreds under a mesh-extended key."""
+        LRU as single-device shreds under a mesh-extended key, placed one
+        shard per device along the plan's shard axes."""
+        num_shards = sp.num_shards
         key = sharded_plan_key(query, rep, mesh, num_shards, self.db.version)
         hit = self._shreds.get(key)
         if hit is not None:
@@ -174,6 +178,8 @@ class QueryEngine:
             return hit.index
         self.stats.shred_builds += 1
         stacked, base = build_stacked(self.db, query, num_shards, rep=rep)
+        stacked = place_stacked(
+            stacked, NamedSharding(auto_mesh(mesh), P(sp.axes)))
         self._shreds[key] = _IndexEntry(stacked, query, self.db.version,
                                         base=base, num_shards=num_shards)
         while len(self._shreds) > self.max_plans:
@@ -276,7 +282,7 @@ class QueryEngine:
         plan = ShardedPlan(
             query=query, spec=spec.plan_view(crep),
             mesh=mesh, axes=sp.axes,
-            stacked=self._stacked_shred_for(query, crep, mesh, sp.num_shards),
+            stacked=self._stacked_shred_for(query, crep, mesh, sp),
             policy=self.policy,
         )
         self._plans[key] = plan
@@ -527,8 +533,13 @@ class QueryEngine:
         (staleness is observable, not inferred — DESIGN.md §11)."""
         plan = self.compile(query, rep=rep)
         tree = build_plan(query)  # the rerooted tree the plan executes
+        pol = config.current_policy()
         lines = [
             f"QueryEngine plan  rep={plan.rep}  method={plan.method}",
+            f"  routes: get={plan.rep_default} draw={plan.route} "
+            f"narrow={plan.narrow}  kernels "
+            f"{'interpreted' if pol.interpret else 'compiled'} on "
+            f"{config.backend()}",
             "  join tree (GYO):",
         ]
         lines += ["    " + l for l in tree.pretty().rstrip().split("\n")]

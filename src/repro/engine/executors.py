@@ -39,7 +39,7 @@ __all__ = [
 def _sample_jit(
     shred: Shred, w, p, prefE, key, cap: int, rep: str, method: str, n: int = 0,
     acap: int = 0, project=None, narrow: bool = False,
-    route: str = "pernode", dparams=None,
+    route: str = "pernode", dparams=None, massE=None,
 ) -> JoinSample:
     if route in ("fused", "reference"):
         # One-launch draw (kernels/fused_draw.py, DESIGN.md §14): positions
@@ -56,7 +56,7 @@ def _sample_jit(
             shred, dparams, key, method=method, cap=cap, acap=acap, n=n)
         cols = probe.gather_columns(shred, node_rows)
     elif method == "exprace":
-        ps = sampling.exprace_positions(key, w, p, prefE, cap,
+        ps = sampling.exprace_positions(key, w, p, prefE, massE, cap,
                                         arrival_cap=acap, narrow=narrow)
     elif method == "ptbern_flat":  # n is the static, concrete join size
         ps = sampling.pt_bern_flat_positions(key, p, prefE, n, cap)
@@ -75,8 +75,9 @@ def sample_executor(method: str, project: Optional[tuple]):
 
     ``cap``/``rep``/``n``/``acap``/``route`` are static: each distinct
     combination is one cached trace on the returned callable. ``dparams``
-    (the plan-bound fused-draw operand vectors) is a pytree operand —
-    ``None`` on the per-node route.
+    (the plan-bound fused-draw operand vectors) and ``massE`` (the
+    plan-bound EXPRACE mass prefix) are pytree operands — ``None`` where
+    the route does not use them.
     """
     return jax.jit(
         partial(_sample_jit, method=method, project=project),
@@ -87,11 +88,11 @@ def sample_executor(method: str, project: Optional[tuple]):
 def _batched_sample_jit(
     shred: Shred, w, p, prefE, keys, cap: int, rep: str, method: str,
     n: int = 0, acap: int = 0, project=None, narrow: bool = False,
-    route: str = "pernode", dparams=None,
+    route: str = "pernode", dparams=None, massE=None,
 ) -> JoinSample:
     one = partial(_sample_jit, shred, w, p, prefE, cap=cap, rep=rep,
                   method=method, n=n, acap=acap, project=project,
-                  narrow=narrow, route=route, dparams=dparams)
+                  narrow=narrow, route=route, dparams=dparams, massE=massE)
     return jax.vmap(one)(keys)
 
 

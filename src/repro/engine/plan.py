@@ -10,10 +10,12 @@ retracing.
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import estimate, probe, sampling
 from repro.core.jointree import JoinQuery
@@ -119,30 +121,40 @@ class CompiledPlan:
             if self.query.prob_var not in root.variables:
                 raise AssertionError("build_plan must reroot prob_var to the root")
             self.p = root.data.column(self.query.prob_var)
+            # Bind-time tables (capacities, the EXPRACE mass prefix, the
+            # fused-draw operands) are float64 scans and reductions over
+            # the plan-bound vectors: computed once per bind in host numpy,
+            # which the TPU compiler would otherwise spend minutes on.
+            w, p = np.asarray(self.w), np.asarray(self.p)
             # Sticky capacities (DESIGN.md §11): recomputed from the new
             # (w, p) but never shrunk below a capacity already traced —
             # a delta that lowers E[k] keeps the cached trace instead of
             # recompiling for a marginally smaller buffer. Growth retraces
             # once, which is the price of not overflowing.
             self._default_cap = max(self._default_cap or 0,
-                                    self.policy.sample_capacity(self.w, self.p))
+                                    self.policy.sample_capacity(w, p))
             self._arrival_cap = max(self._arrival_cap or 0,
-                                    self.policy.arrival_capacity(self.w, self.p))
+                                    self.policy.arrival_capacity(w, p))
+            place = partial(jax.device_put, device=self.w.sharding)
+            self._massE = (place(sampling.exprace_mass(w, p))
+                           if self.method == "exprace" else None)
             # Draw-kernel route (probe.select_draw, DESIGN.md §14), decided
             # once per bind like rep/narrow: the one-launch fused draw needs
-            # its plan-bound operand vectors (eager — concrete arrays) and a
-            # capable shred; recomputed on rebind because a delta can gain
-            # or lose the packed arena.
-            dparams = sampling.fused_draw_params(self.w, self.p, self.prefE)
+            # its plan-bound operand vectors and a capable shred; recomputed
+            # on rebind because a delta can gain or lose the packed arena.
+            dparams = sampling.fused_draw_params(w, p,
+                                                 np.asarray(self.prefE))
             self._route = probe.select_draw(
                 shred, dparams, method=self.method,
                 n=self._join_size if self.method == "ptbern_flat" else 0,
                 kernels=self.spec.kernels)
-            self._dparams = dparams if self._route != "pernode" else None
+            self._dparams = (jax.tree.map(place, dparams)
+                             if self._route != "pernode" else None)
         else:
             self.p = None
             self._route = "pernode"
             self._dparams = None
+            self._massE = None
 
     def rebind_shred(self, shred: Shred) -> "CompiledPlan":
         """Swap in an (incrementally upgraded) index for a newer snapshot,
@@ -151,6 +163,16 @@ class CompiledPlan:
         on the next warm draw (DESIGN.md §11)."""
         self._bind_shred(shred)
         return self
+
+    @property
+    def route(self) -> str:
+        """The draw route bound for this snapshot (``probe.select_draw``)."""
+        return self._route
+
+    @property
+    def narrow(self) -> bool:
+        """Whether the sampler's integer searches run int32-narrowed."""
+        return self._narrow
 
     # -- capacity planning ---------------------------------------------------
     @property
@@ -198,7 +220,8 @@ class CompiledPlan:
         return self._jit(self.shred, self.w, self.p, self.prefE, key, cap=cap,
                          rep=rep or self.rep_default, n=n, acap=acap,
                          narrow=self._narrow, route=route,
-                         dparams=self._dparams if route != "pernode" else None)
+                         dparams=self._dparams if route != "pernode" else None,
+                         massE=self._massE)
 
     def sample_batch(self, keys, cap: Optional[int] = None,
                      rep: Optional[str] = None,
@@ -229,7 +252,8 @@ class CompiledPlan:
                                 cap=cap, rep=rep or self.rep_default, n=n,
                                 acap=acap, narrow=self._narrow, route=route,
                                 dparams=(self._dparams
-                                         if route != "pernode" else None))
+                                         if route != "pernode" else None),
+                                massE=self._massE)
         if int(kpad.shape[0]) != batch:
             smp = jax.tree.map(lambda x: x[:batch], smp)
         return smp

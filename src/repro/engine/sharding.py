@@ -28,11 +28,11 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
-from repro.core import estimate, probe
-from repro.core.distributed import StackedShred, fold_shard_key
+from repro.core import estimate, probe, sampling
+from repro.core.distributed import (StackedShred, fold_shard_key,
+                                    place_stacked)
 from repro.core.jointree import JoinQuery
 from repro.core.poisson import JoinSample
 
@@ -93,6 +93,18 @@ def plan_shards(
     return ShardPlan(axes, n)
 
 
+def auto_mesh(mesh: Mesh) -> Mesh:
+    """``mesh`` with every axis in Auto mode. ``jax.make_mesh`` builds
+    Explicit axes by default, and a shard_map output over an Explicit mesh
+    carries its sharding in its type — indexing it on the host raises. The
+    engine runs its executors over the Auto twin, so results index like
+    any array whichever way the caller built the mesh."""
+    auto = (AxisType.Auto,) * len(mesh.axis_names)
+    if tuple(mesh.axis_types) == auto:
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names, axis_types=auto)
+
+
 class ShardedPlan:
     """One sharded entry of the plan cache: stacked index + shard_map
     executors, keyed by (query fingerprint, rep, method, project, mesh
@@ -119,8 +131,9 @@ class ShardedPlan:
         self._base_rep = "usr" if spec.rep == "both" else spec.rep
         self.method = spec.method
         self.project = spec.project
-        self.mesh = mesh
+        self.mesh = auto_mesh(mesh)
         self.axes = tuple(axes)
+        self.sharding = NamedSharding(self.mesh, P(self.axes))
         self.policy = policy
         self._samplers: Dict[Tuple[int, int], callable] = {}
         self._batched_samplers: Dict[Tuple[int, int], callable] = {}
@@ -128,6 +141,10 @@ class ShardedPlan:
         self._bind_stacked(stacked)
 
     def _bind_stacked(self, stacked: StackedShred) -> None:
+        # Shard s of every stacked leaf lives on the mesh's device s (a
+        # no-op when the engine already placed the index this way): an
+        # index left on one device would be re-sharded by every call.
+        stacked = place_stacked(stacked, self.sharding)
         self.stacked = stacked
         # Executor rep + int32-narrowing selection (probe.select_rep — the
         # same policy as the single-device plan, over the stacked arena
@@ -159,22 +176,28 @@ class ShardedPlan:
         self._bases = np.concatenate(
             [[0], np.cumsum(self.join_sizes)])[:-1].astype(np.int64)
 
-        w, p = stacked.w, stacked.p
-        if p is not None:
-            means = np.asarray(jax.vmap(estimate.expected_sample_size)(w, p))
-            stds = np.asarray(jax.vmap(estimate.sample_std)(w, p))
+        if stacked.p is not None:
+            # Bind-time tables in host numpy over the (S, R) stack, as in
+            # the single-device plan: the TPU compiler takes minutes over
+            # the f64 scans in them.
+            w, p = np.asarray(stacked.w), np.asarray(stacked.p)
+            means = estimate.expected_sample_size(w, p)
+            stds = estimate.sample_std(w, p)
+            masses = estimate.exprace_arrival_mass(w, p)
             # One static capacity for every shard: plan for the heaviest.
             # Sticky across rebinds (DESIGN.md §11): a delta that lowers the
             # estimate keeps the already-traced capacity; growth retraces.
             self.cap = max(getattr(self, "cap", None) or 0, self.policy.plan(
                 float(means.max(initial=0.0)), float(stds.max(initial=1.0))))
-            mass = float(np.asarray(
-                jax.vmap(estimate.exprace_arrival_mass)(w, p)).max(initial=0.0))
+            mass = float(masses.max(initial=0.0))
             self.acap = max(getattr(self, "acap", 0),
                             self.policy.plan(mass * 1.1 + 8, mass ** 0.5))
+            self.massE = jax.device_put(sampling.exprace_mass(w, p),
+                                        self.sharding)
         else:
             self.cap = None
             self.acap = 0
+            self.massE = None
         flat_cap = max(getattr(self, "flat_cap", 0),
                        self.policy.flatten_capacity(
                            max(self.join_sizes, default=0)))
@@ -203,32 +226,34 @@ class ShardedPlan:
 
     # -- shard_map executors -------------------------------------------------
     @staticmethod
-    def _local_sample(shred, w, p, prefE, key, *, cap, acap, rep, method,
-                      project, axes, narrow=False):
+    def _local_sample(shred, w, p, prefE, massE, key, *, cap, acap, rep,
+                      method, project, axes, narrow=False):
         key = fold_shard_key(key, axes)
         # Drop the leading (stacked) singleton shard dim.
-        shred, w, p, prefE = jax.tree.map(lambda x: x[0], (shred, w, p, prefE))
+        shred, w, p, prefE, massE = jax.tree.map(
+            lambda x: x[0], (shred, w, p, prefE, massE))
         s = executors._sample_jit(shred, w, p, prefE, key, cap=cap, rep=rep,
                                   method=method, acap=acap, project=project,
-                                  narrow=narrow)
+                                  narrow=narrow, massE=massE)
         total = jax.lax.psum(s.count, axes)
         # Re-add the shard dim so out_specs can concatenate across shards.
         return jax.tree.map(lambda x: x[None], s), total
 
     @staticmethod
-    def _local_sample_batch(shred, w, p, prefE, keys, *, cap, acap, rep,
-                            method, project, axes, narrow=False):
+    def _local_sample_batch(shred, w, p, prefE, massE, keys, *, cap, acap,
+                            rep, method, project, axes, narrow=False):
         """The batched shard body (DESIGN.md §10): shard_map outside, vmap
         inside. Each lane folds the same shard coordinate into its own base
         key, so lane ``b`` reproduces the single-draw sharded path under
         ``keys[b]`` bit-for-bit; one psum reports the (B,) global counts."""
-        shred, w, p, prefE = jax.tree.map(lambda x: x[0], (shred, w, p, prefE))
+        shred, w, p, prefE, massE = jax.tree.map(
+            lambda x: x[0], (shred, w, p, prefE, massE))
 
         def one(k):
             return executors._sample_jit(
                 shred, w, p, prefE, fold_shard_key(k, axes), cap=cap,
                 rep=rep, method=method, acap=acap, project=project,
-                narrow=narrow)
+                narrow=narrow, massE=massE)
 
         s = jax.vmap(one)(keys)              # leaves: (B, ...)
         totals = jax.lax.psum(s.count, axes)  # (B,) global counts
@@ -246,12 +271,12 @@ class ShardedPlan:
         fn = self._samplers.get((cap, acap))
         if fn is None:
             spec = P(self.axes)
-            fn = jax.jit(shard_map(
+            fn = jax.jit(jax.shard_map(
                 partial(self._local_sample, cap=cap, acap=acap, rep=self.rep,
                         method=self.method, project=self.project,
                         axes=self.axes, narrow=self._narrow),
                 mesh=self.mesh,
-                in_specs=(spec, spec, spec, spec, P()),
+                in_specs=(spec, spec, spec, spec, spec, P()),
                 out_specs=(spec, P()),
                 check_vma=False,
             ))
@@ -262,13 +287,13 @@ class ShardedPlan:
         fn = self._batched_samplers.get((cap, acap))
         if fn is None:
             spec = P(self.axes)
-            fn = jax.jit(shard_map(
+            fn = jax.jit(jax.shard_map(
                 partial(self._local_sample_batch, cap=cap, acap=acap,
                         rep=self.rep, method=self.method,
                         project=self.project, axes=self.axes,
                         narrow=self._narrow),
                 mesh=self.mesh,
-                in_specs=(spec, spec, spec, spec, P()),
+                in_specs=(spec, spec, spec, spec, spec, P()),
                 out_specs=(spec, P()),
                 check_vma=False,
             ))
@@ -278,7 +303,7 @@ class ShardedPlan:
     def _flatten_fn(self):
         if self._flattener is None:
             spec = P(self.axes)
-            self._flattener = jax.jit(shard_map(
+            self._flattener = jax.jit(jax.shard_map(
                 partial(self._local_flatten, cap=self.flat_cap, rep=self.rep),
                 mesh=self.mesh,
                 in_specs=(spec, spec),
@@ -297,7 +322,7 @@ class ShardedPlan:
             raise ValueError("plan has no prob_var; use full_join")
         st = self.stacked
         return self._sampler(cap or self.cap, acap or self.acap)(
-            st.shred, st.w, st.p, st.prefE, key)
+            st.shred, st.w, st.p, st.prefE, self.massE, key)
 
     def _call_overrides(self, spec: Optional[DrawSpec], cap, acap):
         """Per-call ``DrawSpec`` under the explicit kwargs (kwargs win).
@@ -376,7 +401,7 @@ class ShardedPlan:
         st = self.stacked
         smp, _totals = self._batched_sampler(cap or self.cap,
                                              acap or self.acap)(
-            st.shred, st.w, st.p, st.prefE, kpad)
+            st.shred, st.w, st.p, st.prefE, self.massE, kpad)
         # Host gather per lane (padding lanes never gathered), then stack.
         columns = {v: np.asarray(a) for v, a in smp.columns.items()}
         positions = np.asarray(smp.positions)   # (S, Bp, cap)
@@ -424,5 +449,6 @@ class ShardedPlan:
     def lower_step(self):
         st = self.stacked
         key = jax.ShapeDtypeStruct((), jax.random.key(0).dtype)
-        args = jax.eval_shape(lambda: (st.shred, st.w, st.p, st.prefE))
+        args = jax.eval_shape(
+            lambda: (st.shred, st.w, st.p, st.prefE, self.massE))
         return self._sampler(self.cap, self.acap).lower(*args, key)

@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import config
+
 DEFAULT_BLOCK_S = 512
 NEG_INF = -1e30
 
@@ -58,6 +60,7 @@ def _kernel(q_ref, k_ref, v_ref, bias_ref, out_ref, m_ref, l_ref, acc_ref,
         out_ref[0] = out.astype(out_ref.dtype)
 
 
+@config.resolves_interpret
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
 def flash_decode(
     q: jnp.ndarray,      # (B, H, D)
@@ -66,7 +69,7 @@ def flash_decode(
     bias: jnp.ndarray,   # (B, S)  additive, 0 or -inf (padding/window mask)
     *,
     block_s: int = DEFAULT_BLOCK_S,
-    interpret: bool = True,
+    interpret: bool = None,
 ) -> jnp.ndarray:
     B, H, D = q.shape
     _, KV_H, S, _ = k.shape

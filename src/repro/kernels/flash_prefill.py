@@ -24,6 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import config
+
 DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_K = 512
 NEG_INF = -1e30
@@ -66,6 +68,7 @@ def _kernel(q_ref, k_ref, v_ref, out_ref, m_ref, l_ref, acc_ref,
         out_ref[0, 0] = out.astype(out_ref.dtype)
 
 
+@config.resolves_interpret
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
                                              "interpret"))
 def flash_prefill(
@@ -76,7 +79,7 @@ def flash_prefill(
     causal: bool = True,
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool = True,
+    interpret: bool = None,
 ) -> jnp.ndarray:
     B, H, S, D = q.shape
     KV = k.shape[1]

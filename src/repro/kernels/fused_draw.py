@@ -42,6 +42,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import config
+
 from . import threefry
 from .tree_probe import tree_walk
 
@@ -206,11 +208,12 @@ def _kernel(arena_ref, key_ref, *rest, layout, method, cap, acap, n):
     ovf_ref[0] = overflow.astype(I32)
 
 
+@config.resolves_interpret
 @functools.partial(
     jax.jit, static_argnames=("layout", "method", "cap", "acap", "n",
                               "interpret"))
 def fused_draw(arena, key_data, params, *, layout, method: str, cap: int,
-               acap: int = 0, n: int = 0, interpret: bool = True):
+               acap: int = 0, n: int = 0, interpret: bool = None):
     """The one-launch draw. arena: (layout.size,) int32 packed index;
     key_data: (2,) uint32 (``jax.random.key_data``); params: the
     ``sampling.fused_draw_params`` dict. Returns
@@ -256,10 +259,11 @@ def _sample_kernel(key_ref, *rest, method, cap, acap, n):
     ovf_ref[0] = overflow.astype(I32)
 
 
+@config.resolves_interpret
 @functools.partial(
     jax.jit, static_argnames=("method", "cap", "acap", "n", "interpret"))
 def fused_sample(key_data, params, *, method: str, cap: int, acap: int = 0,
-                 n: int = 0, interpret: bool = True):
+                 n: int = 0, interpret: bool = None):
     """The sampling HALF of the fused draw as its own one-launch kernel:
     ``draw_core`` without the tree walk — key -> ``(positions (cap,) i32,
     count () i32, overflow () bool)``, PositionSample conventions.

@@ -2,9 +2,11 @@
 
 Each wrapper owns layout plumbing (1-D <-> (rows, 128) retiling, padding)
 and the documented fallbacks:
-  * int64 offsets (joins > 2^31) fall back to XLA searchsorted/cumsum —
-    TPU has no native 64-bit gathers (DESIGN.md §9);
-  * prefix tables too large for VMEM fall back likewise.
+  * int64 offsets (joins > 2^31) fall back to ``searchsorted`` and a
+    blocked XLA scan — TPU has no native 64-bit gathers (DESIGN.md §9);
+  * prefix tables above the compare-and-count kernel's ``MAX_TABLE``
+    take ``searchsorted`` (a row-gather search tree on a TPU, XLA's
+    binary search elsewhere).
 
 Kernel selection is resolved *at call time* from the active
 ``repro.config.KernelPolicy`` (DESIGN.md §14): every wrapper takes an
@@ -31,17 +33,17 @@ from repro import config
 
 from . import ref as _ref
 from .autotune import tile_for
+from .bsearch_probe import MAX_TABLE as _BSEARCH_MAX_TABLE
 from .bsearch_probe import bsearch_probe as _bsearch_tiles
 from .geo_gaps import geo_gaps_tiles as _geo_tiles
 from .prefix_sum import prefix_sum_tiles as _prefix_tiles
 from .flash_decode import flash_decode as _flash_decode
 from .flash_prefill import flash_prefill as _flash_prefill
 
-# int32 table entries kept fully VMEM-resident (bsearch prefix tables and
-# the fused-GET arena share this budget — core/probe.py reads the active
-# policy's ``vmem_limit``; this constant is the policy default).
+# int32 table entries kept fully VMEM-resident (the fused-GET arena's
+# budget — core/probe.py reads the active policy's ``vmem_limit``; this
+# constant is the policy default).
 VMEM_PREF_LIMIT = config.DEFAULT_VMEM_LIMIT
-_VMEM_PREF_LIMIT = VMEM_PREF_LIMIT  # back-compat alias
 
 
 def interpret_default(policy: Optional[config.KernelPolicy] = None) -> bool:
@@ -86,6 +88,70 @@ def to_tiles(x: jnp.ndarray, fill=0) -> jnp.ndarray:
     return jnp.pad(x, (0, pad), constant_values=fill).reshape(rows, 128)
 
 
+SEARCH_FAN = 128          # table entries per node of the search tree
+SEARCH_CHUNK = 1 << 14    # queries per step (bounds the (chunk, fan) tile)
+
+
+def kary_searchsorted(table: jnp.ndarray, q: jnp.ndarray,
+                      side: str = "right", *, fan: int = SEARCH_FAN,
+                      chunk: int = SEARCH_CHUNK) -> jnp.ndarray:
+    """``jnp.searchsorted(table, q, side)`` as a ``fan``-ary search tree.
+
+    Level 0 is the table padded and retiled ``(rows, fan)``; level ``i+1``
+    holds the first entry of every row of level ``i``, until one row
+    remains. A query descends one level per step by fetching the row its
+    count so far selects (one row gather) and counting that row's entries
+    at or below it (``<`` for ``side='left'``): about log_fan(n) gathers
+    of whole rows, against log2(n) scalar gathers for a binary search.
+    The result is exact for any sorted table, duplicates included: every
+    entry of an earlier row is at most the chosen row's first entry, and
+    no entry of a later row qualifies. Queries run ``chunk`` at a time."""
+    if side not in ("left", "right"):
+        raise ValueError(f"side must be 'left' or 'right', got {side!r}")
+    n = table.shape[0]
+    dt = jnp.promote_types(table.dtype, q.dtype)
+    table, q = table.astype(dt), q.astype(dt)
+    big = (jnp.inf if jnp.issubdtype(dt, jnp.floating)
+           else jnp.iinfo(dt).max)
+    levels, cur = [], table
+    while True:
+        rows = max(-(-cur.shape[0] // fan), 1)
+        lv = jnp.pad(cur, (0, rows * fan - cur.shape[0]),
+                     constant_values=big).reshape(rows, fan)
+        levels.append(lv)
+        if rows == 1:
+            break
+        cur = lv[:, 0]
+    levels.reverse()
+
+    def descend(qc):
+        c = jnp.zeros(qc.shape, jnp.int32)
+        for i, lv in enumerate(levels):
+            row = lv[c]
+            hit = row <= qc[:, None] if side == "right" else row < qc[:, None]
+            cnt = jnp.sum(hit, axis=1, dtype=jnp.int32)
+            c = c * fan + (cnt if i == len(levels) - 1
+                           else jnp.maximum(cnt - 1, 0))
+        return jnp.minimum(c, n)  # pads (dtype max) count only for q == max
+
+    k = q.shape[0]
+    chunk = min(chunk, max(k, 1))
+    kp = -(-k // chunk) * chunk
+    qp = jnp.pad(q, (0, kp - k)).reshape(-1, chunk)
+    return jax.lax.map(descend, qp).reshape(-1)[:k]
+
+
+def searchsorted(table: jnp.ndarray, q: jnp.ndarray,
+                 side: str = "right") -> jnp.ndarray:
+    """``jnp.searchsorted(table, q, side)``, int32 result, by the form that
+    is fast on the backend: ``kary_searchsorted`` on a TPU, where every
+    step of a binary search is a scalar gather, XLA's binary search
+    elsewhere."""
+    if config.backend() == "tpu":
+        return kary_searchsorted(table, q, side)
+    return jnp.searchsorted(table, q, side=side)
+
+
 def searchsorted_prefix(pref: jnp.ndarray, q: jnp.ndarray,
                         *, interpret: Optional[bool] = None,
                         policy: Optional[config.KernelPolicy] = None,
@@ -93,15 +159,17 @@ def searchsorted_prefix(pref: jnp.ndarray, q: jnp.ndarray,
     """Bulk 'locate offset in prefix vector': max j with pref[j] <= q
     (== ``searchsorted(pref, q, 'right') - 1`` clamped at 0).
 
-    Pallas fast path for int32 tables/queries that fit VMEM; identical XLA
-    fallback for every other dtype (int64 joins > 2^31, float mass
-    vectors) or oversized table — "where dtypes permit" (DESIGN.md §9).
+    Pallas compare-and-count path for int32 tables of at most
+    ``bsearch_probe.MAX_TABLE`` entries (its work is O(table) per query);
+    identical XLA binary search for every other dtype (int64 joins > 2^31,
+    float mass vectors) or larger table (DESIGN.md §9).
     """
     n = q.shape[0]
     pol = config.current_policy(policy)
     if (pref.dtype != jnp.int32 or q.dtype != jnp.int32
-            or pref.shape[0] > pol.vmem_limit or not pallas_enabled(pol)):
-        return jnp.maximum(jnp.searchsorted(pref, q, side="right") - 1, 0)
+            or pref.shape[0] > min(_BSEARCH_MAX_TABLE, pol.vmem_limit)
+            or not pallas_enabled(pol)):
+        return jnp.maximum(searchsorted(pref, q) - 1, 0)
     tiles = to_tiles(q)
     out = _bsearch_tiles(pref, tiles,
                          block_rows=tile_for("bsearch_probe", n, pol),
@@ -109,14 +177,33 @@ def searchsorted_prefix(pref: jnp.ndarray, q: jnp.ndarray,
     return out.reshape(-1)[:n]
 
 
+def blocked_cumsum(x: jnp.ndarray, block: int = 1024) -> jnp.ndarray:
+    """Inclusive prefix sum of a 1-D vector as a two-level XLA scan: scan
+    ``block``-wide rows, then add the scanned row totals (recursively).
+    Exact for integers — the same values as ``jnp.cumsum`` — and the form
+    the TPU compiler handles at index sizes: its ``cumsum`` over one
+    multi-million-element window of emulated int64 takes minutes to
+    compile, the blocked scan seconds."""
+    n = x.shape[0]
+    if n <= block:
+        return jnp.cumsum(x)
+    xp = jnp.pad(x, (0, (-n) % block)).reshape(-1, block)
+    inner = jnp.cumsum(xp, axis=1)
+    totals = inner[:, -1]
+    offsets = blocked_cumsum(totals, block) - totals
+    return (inner + offsets[:, None]).reshape(-1)[:n]
+
+
 def prefix_sum(x: jnp.ndarray, exclusive: bool = False,
                *, interpret: Optional[bool] = None,
                policy: Optional[config.KernelPolicy] = None) -> jnp.ndarray:
-    """Prefix sum of a 1-D vector (the index's pref column)."""
+    """Prefix sum of a 1-D vector (the index's pref column): the Pallas
+    kernel for int32/float32, ``blocked_cumsum`` for every other dtype or
+    with kernels disabled."""
     n = x.shape[0]
     pol = config.current_policy(policy)
-    if x.dtype == jnp.int64 or not pallas_enabled(pol):
-        s = jnp.cumsum(x)
+    if x.dtype not in (jnp.int32, jnp.float32) or not pallas_enabled(pol):
+        s = blocked_cumsum(x)
     else:
         s = _prefix_tiles(to_tiles(x),
                           interpret=_interpret(interpret, pol)).reshape(-1)[:n]

@@ -2,9 +2,11 @@
 
 Index construction's only non-sort hot loop is the prefix sum over tuple
 weights (paper §4: "The prefix vector can clearly be computed in linear
-time"). TPU grids execute sequentially per core, so a single scalar carry in
-SMEM threads the running total through the (row-tiled) grid — one pass, no
-log-depth scan tree, exactly one VMEM read + write per element.
+time"). TPU grids execute sequentially per core, so one carry (the running total,
+broadcast in a (1, 128) VMEM scratch) threads through the (row-tiled) grid —
+one pass, exactly one VMEM read + write per element. Within a tile the scan
+is the log-step roll scan of ``kernels/scan.py`` (Mosaic has no
+``cumsum``).
 
 Layout: 1-D data is retiled to (rows, 128) by ops.py; each grid step owns a
 (block_rows, 128) tile and computes its flat (row-major) running sum.
@@ -18,40 +20,35 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import config
+
+from .scan import carried_cumsum
+
 DEFAULT_BLOCK_ROWS = 64
 
 
 def _kernel(x_ref, out_ref, carry_ref):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        carry_ref[0] = jnp.zeros((), x_ref.dtype)
-
-    x = x_ref[...]
-    # dtype pinned: under jax x64 (enabled by repro.core) jnp.sum would
-    # promote int32 -> int64, which the int32 out_ref store rejects.
-    row_sum = jnp.sum(x, axis=1, dtype=x.dtype)
-    row_off = jnp.cumsum(row_sum) - row_sum  # exclusive row offsets
-    flat = jnp.cumsum(x, axis=1) + row_off[:, None] + carry_ref[0]
-    out_ref[...] = flat
-    carry_ref[0] = carry_ref[0] + jnp.sum(row_sum, dtype=x.dtype)
+    out_ref[...] = carried_cumsum(x_ref[...], carry_ref)
 
 
+@config.resolves_interpret
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def prefix_sum_tiles(
-    x: jnp.ndarray, *, block_rows: int = DEFAULT_BLOCK_ROWS, interpret: bool = True
-) -> jnp.ndarray:
-    """Inclusive prefix sum in flat row-major order over (R, 128) tiles."""
+def prefix_sum_tiles(x: jnp.ndarray, *, block_rows: int = DEFAULT_BLOCK_ROWS,
+                     interpret: bool = None) -> jnp.ndarray:
+    """Inclusive prefix sum in flat row-major order over (R, 128) tiles
+    (int32 or float32). ``interpret=None`` takes the active policy's mode."""
     assert x.ndim == 2 and x.shape[1] == 128, x.shape
     rows = x.shape[0]
-    grid = (pl.cdiv(rows, block_rows),)
-    return pl.pallas_call(
-        _kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((block_rows, 128), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((block_rows, 128), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        scratch_shapes=[pltpu.SMEM((1,), x.dtype)],
-        interpret=interpret,
-    )(x)
+    block_rows = min(block_rows, rows)
+    # Traced with x64 off: under jax x64 (which repro.core enables) index
+    # maps and loop counters become int64, which Mosaic rejects.
+    with jax.enable_x64(False):
+        return pl.pallas_call(
+            _kernel,
+            grid=(pl.cdiv(rows, block_rows),),
+            in_specs=[pl.BlockSpec((block_rows, 128), lambda i: (i, 0))],
+            out_specs=pl.BlockSpec((block_rows, 128), lambda i: (i, 0)),
+            out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+            scratch_shapes=[pltpu.VMEM((1, 128), x.dtype)],
+            interpret=interpret,
+        )(x)

@@ -106,6 +106,7 @@ def _kernel(arena_ref, q_ref, out_ref, *, layout):
         out_ref[s, :, :] = r
 
 
+@config.resolves_interpret
 @functools.partial(jax.jit,
                    static_argnames=("layout", "block_rows", "interpret"))
 def tree_probe(
@@ -114,7 +115,7 @@ def tree_probe(
     *,
     layout,
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool = None,
 ) -> jnp.ndarray:
     """arena: (layout.size,) int32 packed index; q: (R, 128) int32 probe
     positions. Returns (layout.num_slots, R, 128) int32 — the row index of
@@ -282,7 +283,7 @@ def _paged_dma(pages, q, *, layout, block_rows, interpret):
     return pl.pallas_call(
         functools.partial(_dma_paged_kernel, layout=layout),
         grid=grid,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec((block_rows, 128), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((layout.num_slots, block_rows, 128),
                                lambda i: (0, i, 0)),
@@ -309,7 +310,7 @@ def tree_probe_paged(
     *,
     layout,
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool = None,
     dma: bool = None,
 ) -> jnp.ndarray:
     """Paged twin of ``tree_probe``: same contract — ``q`` is (R, 128)
@@ -323,5 +324,6 @@ def tree_probe_paged(
     if dma is None:
         dma = config.backend() == "tpu"
     return _tree_probe_paged(tuple(pages), q, layout=layout,
-                             block_rows=block_rows, interpret=interpret,
+                             block_rows=block_rows,
+                             interpret=config.resolve_interpret(interpret),
                              dma=bool(dma))

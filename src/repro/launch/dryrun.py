@@ -28,7 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import configs
+from repro import config, configs
 from repro.launch.hlo_cost import HloCost
 from repro.launch.mesh import batch_axes, make_production_mesh
 from repro.models import config as mcfg
@@ -37,9 +37,11 @@ from repro.optim import AdamWConfig, adamw_init, adamw_update
 
 OUT_DIR = Path(__file__).resolve().parents[3] / "experiments" / "dryrun"
 
-# v5e hardware constants (per chip) — §Roofline
-PEAK_FLOPS = 197e12      # bf16
-HBM_BW = 819e9           # bytes/s
+# v5e hardware constants (per chip) — §Roofline; peaks from the one
+# device_kind-keyed table in repro.config.
+_V5E = config.PEAK_FLOPS["TPU v5 lite"]
+PEAK_FLOPS = _V5E["flops_bf16"]
+HBM_BW = _V5E["hbm_bytes_per_s"]
 ICI_BW = 50e9            # bytes/s/link
 
 

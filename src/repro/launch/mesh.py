@@ -6,16 +6,10 @@ touches jax device state.
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5 exposes explicit axis types; older releases don't.
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - version-dependent
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
@@ -32,6 +26,11 @@ def make_host_mesh(model: int = 1):
     n = len(jax.devices())
     assert n % model == 0
     return _make_mesh((n // model, model), ("data", "model"))
+
+
+def make_data_mesh(n: int):
+    """A 1-D ``("data",)`` mesh over the first ``n`` devices, Auto axes."""
+    return _make_mesh((n,), ("data",))
 
 
 def force_host_devices(n: int) -> int:

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import configs
+from repro import config, configs
 from repro.launch.metrics import percentile
 from repro.launch.fleet import (  # noqa: F401  (re-exported public API)
     JoinSampleRequest, MicroBatcher, Rejected, UpdateRequest,
@@ -112,12 +112,11 @@ def _join_demo(n_requests: int, devices: int = 1, max_batch: int = 64,
                max_wait_ms: float = 2.0, updates: int = 0) -> None:
     from repro.data.pipeline import make_corpus_db
     from repro.engine import QueryEngine
-    from repro.launch.mesh import force_host_devices
+    from repro.launch.mesh import force_host_devices, make_data_mesh
 
     mesh = None
     if devices > 1:
-        n = force_host_devices(devices)
-        mesh = jax.make_mesh((n,), ("data",))
+        mesh = make_data_mesh(force_host_devices(devices))
 
     db = make_corpus_db(n_docs=20_000, n_clusters=64, seq_len=8, vocab=256)
     reqs, (q_qual, _) = _demo_stream(db, n_requests, updates)
@@ -242,6 +241,7 @@ def main():
                     help="fleet mode: skip the injected mid-stream replica "
                          "crash")
     args = ap.parse_args()
+    config.enable_compile_cache()
     if args.mode == "join":
         if args.replicas > 1:
             _fleet_demo(args.requests, args.replicas,

@@ -33,7 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import configs
+from repro import config, configs
 from repro.checkpoint import CheckpointManager
 from repro.data import PoissonJoinSource, SyntheticLMSource, make_corpus_db
 from repro.launch.mesh import batch_axes, make_host_mesh
@@ -176,6 +176,7 @@ def main():
     ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
     ap.add_argument("--full", action="store_true", help="full (non-reduced) config")
     args = ap.parse_args()
+    config.enable_compile_cache()
     out = train(TrainConfig(arch=args.arch, steps=args.steps, batch=args.batch,
                             seq_len=args.seq_len, data=args.data,
                             ckpt_dir=args.ckpt_dir, reduced=not args.full))

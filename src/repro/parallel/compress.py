@@ -19,8 +19,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size
-
 
 def compress_int8(g: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Symmetric per-tensor int8 quantization. Returns (q, scale)."""
@@ -40,7 +38,7 @@ def compressed_psum_grads(grads, err, axis: str):
     grads/err: pytrees of equal structure. Returns (mean_grads, new_err).
     Scales are psum-maxed so every shard dequantizes consistently.
     """
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
 
     def one(g, e):
         corrected = g.astype(jnp.float32) + e

@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.compat import shard_map
 
 
 def pipeline_forward(
@@ -75,7 +74,7 @@ def pipeline_forward(
         return outs[None]  # re-add stage dim for out_specs
 
     spec_p = jax.tree.map(lambda _: P(axis), stage_params)
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(spec_p, P()),          # batch replicated across stages
         out_specs=P(axis),

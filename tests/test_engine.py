@@ -214,3 +214,25 @@ def test_prob_var_required_for_poisson(db):
     # ... but full_join on the same query is fine.
     full = engine.full_join(q)
     assert len(next(iter(full.values()))) == engine.join_size(q)
+
+
+def test_tpu_search_route_is_bit_identical(db, query, monkeypatch):
+    """The TPU's row-gather search (``ops.kary_searchsorted``), put in
+    place of XLA's binary search on every engine path, changes no result:
+    full join, single draws and a batch, bit for bit."""
+    from repro.kernels import ops
+
+    def run():
+        eng = QueryEngine(db)
+        keys = jax.random.split(jax.random.key(3), 4)
+        return (eng.full_join(query),
+                [eng.poisson_sample(query, jax.random.key(s)) for s in (0, 1)],
+                eng.sample_batch(query, keys))
+
+    want = run()
+    monkeypatch.setattr(ops, "searchsorted",
+                        lambda t, q, side="right": ops.kary_searchsorted(
+                            t, q, side, fan=8, chunk=64))
+    got = run()
+    for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -96,9 +96,12 @@ def test_cross_product_and_extreme_p_property(data):
     })
     q = JoinQuery((Atom.of("F", "a"), Atom.of("E", "w")))
     shred = build_shred(db, q, rep="usr")
+    # One probability per row of the join tree's root (GYO may root the
+    # tree at either relation).
+    nroot = shred.root.num_rows
     p = jnp.asarray(data.draw(
         st.lists(st.sampled_from([0.01, 0.3, 0.5, 0.7, 0.99]),
-                 min_size=nf, max_size=nf), label="p"))
+                 min_size=nroot, max_size=nroot), label="p"))
     assert_fused_draw_matches(shred, p=p)
 
 
@@ -288,8 +291,8 @@ class TestKernelPolicy:
         assert not config.current_policy().enabled
         monkeypatch.setenv("REPRO_PALLAS_DISABLE", "0")
         assert config.current_policy().enabled
-        # Historical empty-string semantics: INTERPRET='' means True (the
-        # CI matrix relies on it), PREFER='' means False.
+        # Empty-string semantics: INTERPRET='' means the backend default
+        # (interpret on this CPU backend), PREFER='' means False.
         monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "")
         monkeypatch.setenv("REPRO_PALLAS_PREFER", "")
         pol = config.current_policy()

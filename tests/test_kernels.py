@@ -50,6 +50,34 @@ class TestBsearchProbe:
         assert (np.asarray(q) < prefn[got + 1]).all()
 
 
+class TestKarySearchsorted:
+    """The row-gather search tree equals numpy's binary search exactly:
+    every table size around the fan-out boundaries, runs of duplicates,
+    queries outside the table, both sides, int and float dtypes."""
+
+    @pytest.mark.parametrize("n", [0, 1, 5, 128, 129, 16387, 70001])
+    @pytest.mark.parametrize("side", ["left", "right"])
+    @pytest.mark.parametrize("dtype", [np.int64, np.int32, np.float64])
+    def test_matches_numpy(self, n, side, dtype):
+        rng = np.random.default_rng(n)
+        hi = 40 if n < 2000 else 10 ** 9  # small tables: long equal runs
+        t = np.sort(rng.integers(0, hi, n)).astype(dtype)
+        q = rng.integers(-2, hi + 3, 5003).astype(dtype)
+        got = ops.kary_searchsorted(jnp.asarray(t), jnp.asarray(q), side,
+                                    chunk=1024)
+        assert got.dtype == jnp.int32
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.searchsorted(t, q, side))
+
+    def test_dtype_max_query_and_small_fan(self):
+        t = np.array([0, 3, 3, 9], np.int64)
+        q = np.array([np.iinfo(np.int64).max, -1, 3, 9], np.int64)
+        for fan in (2, 3, 128):
+            got = ops.kary_searchsorted(jnp.asarray(t), jnp.asarray(q),
+                                        fan=fan)
+            np.testing.assert_array_equal(np.asarray(got), [4, 0, 3, 4])
+
+
 class TestOpsDispatch:
     """Call-time behavior of the ops wrappers: env flags are read per call
     (not frozen at import), explicit ``interpret=`` overrides win, and

@@ -12,12 +12,8 @@ SCRIPT = textwrap.dedent("""
     from repro.parallel.pipeline import pipeline_forward, reference_forward
 
     assert len(jax.devices()) == 4
-    try:                         # jax >= 0.5; older releases have no AxisType
-        from jax.sharding import AxisType
-        mesh = jax.make_mesh((4,), ("stage",),
-                             axis_types=(AxisType.Auto,))
-    except ImportError:
-        mesh = jax.make_mesh((4,), ("stage",))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((4,), ("stage",), axis_types=(AxisType.Auto,))
 
     D = 16
     def stage_fn(p, x):          # shape-preserving block

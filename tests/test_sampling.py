@@ -82,12 +82,13 @@ class TestExprace:
         p = jnp.asarray(pv, jnp.float64)
         prefE = jnp.concatenate([jnp.zeros(1, jnp.int64), jnp.cumsum(w)])
         nf = int(prefE[-1])
+        massE = sampling.exprace_mass(w, p)
         fn = jax.jit(partial(sampling.exprace_positions, cap=cap, arrival_cap=acap))
         seen = np.zeros(nf)
         pair = np.zeros((nf, nf))
         counts = []
         for s in range(nseeds):
-            ps = fn(jax.random.key(s), w, p, prefE)
+            ps = fn(jax.random.key(s), w, p, prefE, massE)
             assert not bool(ps.overflow)
             c = int(ps.count)
             counts.append(c)
@@ -123,7 +124,7 @@ class TestExprace:
         p = jnp.asarray([1.0, 0.0], jnp.float64)
         prefE = jnp.asarray([0, 5, 9], jnp.int64)
         ps = jax.jit(partial(sampling.exprace_positions, cap=16, arrival_cap=16))(
-            jax.random.key(0), w, p, prefE)
+            jax.random.key(0), w, p, prefE, sampling.exprace_mass(w, p))
         assert int(ps.count) == 5
         assert np.array_equal(np.asarray(ps.positions)[:5], np.arange(5))
 
@@ -132,10 +133,29 @@ class TestExprace:
         p = jnp.asarray([1.0, 0.5, 1.0], jnp.float64)
         prefE = jnp.asarray([0, 0, 6, 6], jnp.int64)
         fn = jax.jit(partial(sampling.exprace_positions, cap=16, arrival_cap=32))
+        massE = sampling.exprace_mass(w, p)
         for s in range(50):
-            ps = fn(jax.random.key(s), w, p, prefE)
+            ps = fn(jax.random.key(s), w, p, prefE, massE)
             pos = np.asarray(ps.positions)[: int(ps.count)]
             assert (pos < 6).all()
+
+    def test_host_mass_matches_device_scan(self):
+        """The host numpy mass prefix plans bind agrees with the f64 device
+        scan of the same rates (summation order differs: rtol 1e-12), and
+        a stacked (S, R) input is the per-shard prefix."""
+        rng = np.random.default_rng(5)
+        w = rng.integers(0, 40, (3, 257))
+        p = rng.random((3, 257))
+        p[:, :5] = [0.0, 0.5, 0.51, 1.0, 0.999]
+        massE = sampling.exprace_mass(w, p)
+        assert massE.shape == (3, 258) and massE.dtype == np.float64
+        for s in range(3):
+            _, lam = sampling._exprace_rates(jnp.asarray(p[s]))
+            dev = np.concatenate([[0.0], np.asarray(
+                jnp.cumsum(jnp.asarray(w[s], jnp.float64) * lam))])
+            np.testing.assert_allclose(massE[s], dev, rtol=1e-12, atol=0)
+            np.testing.assert_array_equal(
+                massE[s], sampling.exprace_mass(w[s], p[s]))
 
     def test_matches_host_oracle_distribution(self):
         """EXPRACE count distribution == paper-faithful sequential PT* oracle."""
@@ -144,7 +164,9 @@ class TestExprace:
         p = jnp.asarray(pv, jnp.float64)
         prefE = jnp.concatenate([jnp.zeros(1, jnp.int64), jnp.cumsum(w)])
         fn = jax.jit(partial(sampling.exprace_positions, cap=64, arrival_cap=96))
-        ours = [int(fn(jax.random.key(s), w, p, prefE).count) for s in range(400)]
+        massE = sampling.exprace_mass(w, p)
+        ours = [int(fn(jax.random.key(s), w, p, prefE, massE).count)
+                for s in range(400)]
         rng = np.random.default_rng(0)
         host = [len(sampling.pt_positions_host(rng, wv, pv, "hybrid")) for _ in range(400)]
         # two-sample z-test on means

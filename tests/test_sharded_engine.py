@@ -141,7 +141,8 @@ def test_sharded_sample_is_valid_and_fold_reproducible(db, query, mesh):
         shred_s = jax.tree.map(lambda x: x[s], st.shred)
         r = _sample_jit(shred_s, st.w[s], st.p[s], st.prefE[s],
                         jax.random.fold_in(key, s), cap=plan.cap,
-                        rep=plan.rep, method="exprace", acap=plan.acap)
+                        rep=plan.rep, method="exprace", acap=plan.acap,
+                        massE=plan.massE[s])
         c = int(r.count)
         ref += _tuples(r.columns, keys, c)
         ref_pos += list(np.asarray(r.positions)[:c] + base)
@@ -233,6 +234,13 @@ SCRIPT = textwrap.dedent("""
     engine = QueryEngine(db)
     plan = engine.compile_sharded(q, mesh)      # auto planner, real 8 shards
     assert isinstance(plan, ShardedPlan) and plan.num_shards == 8
+
+    # The stacked index (and the plan's mass prefix) is placed one shard
+    # per device, not left whole on the first device.
+    st = plan.stacked
+    for x in jax.tree.leaves((st.shred, st.w, st.p, st.prefE, plan.massE)):
+        assert len(x.sharding.device_set) == 8, x.sharding
+        assert x.sharding.shard_shape(x.shape)[0] == 1, x.sharding
 
     # Sharded sample == the single-device engine under the same
     # seed-folding scheme (one plain-engine draw per shard block).

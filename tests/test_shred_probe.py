@@ -231,3 +231,29 @@ def test_cached_csr_probe_equals_plain():
     b = csr_get_rows_cached(shred, pos)
     for k in a:
         np.testing.assert_array_equal(np.asarray(a[k]), np.asarray(b[k]))
+
+
+@pytest.mark.parametrize("hi", [1 << 20, 1 << 40, (1 << 53) - 1])
+def test_mixed_radix_split_is_exact_divmod(hi):
+    """GET's float64 divmod equals integer floor division for every
+    dividend below the join-size bound, including quotients that round up
+    to the next integer and negative (masked-lane) dividends."""
+    from repro.core.probe import _mixed_radix_split
+    rng = np.random.default_rng(hi % 997)
+    a = rng.integers(-hi, hi, 20000, dtype=np.int64)
+    b = rng.integers(1, 1 << 31, 20000, dtype=np.int64)
+    # q*b - 1 puts a/b just below an integer: the closest a quotient gets.
+    q = a // b
+    a = np.concatenate([a, np.clip(q * b - 1, -hi, hi - 1), [hi - 1, 0, -1]])
+    b = np.concatenate([b, b, [3, 7, 5]])
+    r, q = _mixed_radix_split(jnp.asarray(a), jnp.asarray(b))
+    np.testing.assert_array_equal(np.asarray(r), a % b)
+    np.testing.assert_array_equal(np.asarray(q), a // b)
+
+
+def test_root_prefix_refuses_joins_beyond_bound():
+    from repro.core.shred import MAX_JOIN, root_prefix
+    np.testing.assert_array_equal(np.asarray(root_prefix([2, 0, 3])),
+                                  [0, 2, 2, 5])
+    with pytest.raises(ValueError, match="2\\^53"):
+        root_prefix([MAX_JOIN // 2, MAX_JOIN // 2])
