@@ -547,13 +547,14 @@ def csr_get_rows_cached(shred: Shred, pos: jnp.ndarray) -> Dict[str, jnp.ndarray
 
 def get_rows(shred: Shred, pos: jnp.ndarray, rep: str = None) -> Dict[str, jnp.ndarray]:
     rep = rep or ("usr" if shred.rep in ("usr", "both") else "csr")
-    if rep == "usr_fused":
-        return usr_get_rows_fused(shred, pos)
-    if rep == "usr_paged":
-        return usr_get_rows_paged(shred, pos)
-    if rep == "usr":
-        return usr_get_rows(shred, pos)
-    return csr_get_rows(shred, pos)
+    with jax.named_scope("usr_locate"):
+        if rep == "usr_fused":
+            return usr_get_rows_fused(shred, pos)
+        if rep == "usr_paged":
+            return usr_get_rows_paged(shred, pos)
+        if rep == "usr":
+            return usr_get_rows(shred, pos)
+        return csr_get_rows(shred, pos)
 
 
 def gather_columns(shred: Shred, node_rows: Dict[str, jnp.ndarray]) -> Dict[str, jnp.ndarray]:
@@ -561,10 +562,11 @@ def gather_columns(shred: Shred, node_rows: Dict[str, jnp.ndarray]) -> Dict[str,
     GET).  Shared by the positional routes (``get``) and the fused draw,
     whose kernel already resolved the rows in-launch."""
     out: Dict[str, jnp.ndarray] = {}
-    for node in shred.root.nodes():
-        rows = node_rows[node.name]
-        for v in node.owned:
-            out[v] = jnp.take(node.data.column(v), rows, axis=0)
+    with jax.named_scope("usr_gather"):
+        for node in shred.root.nodes():
+            rows = node_rows[node.name]
+            for v in node.owned:
+                out[v] = jnp.take(node.data.column(v), rows, axis=0)
     return out
 
 

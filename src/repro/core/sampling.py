@@ -233,68 +233,81 @@ def exprace_positions(
     acap = arrival_cap or cap
     R = w.shape[0]
     n = prefE[-1]
-    kM, kV = jax.random.split(key)
-    comp, lam = _exprace_rates(p)
+    with jax.named_scope("exprace"):
+        # --- Poisson arrivals over the piecewise-constant-rate line --------
+        with jax.named_scope("exprace_arrivals"):
+            kM, kV = jax.random.split(key)
+            comp, lam = _exprace_rates(p)
+            Lam = massE[-1]
+            M = jax.random.poisson(kM, Lam).astype(I64)
+            aM = jnp.minimum(M, acap)
+            v = jax.random.uniform(kV, (acap,), F64) * Lam
+            avalid = jnp.arange(acap, dtype=I64) < aM
+            # Inverse-CDF arrival placement: float mass vector, so the ops
+            # wrapper always takes its XLA fallback here (dtypes never
+            # permit int32).
+            r = _locate_prefix(massE, v, R - 1, False)
+            cell = jnp.floor((v - massE[r])
+                             / jnp.maximum(lam[r], _TINY)).astype(I64)
+            cell = jnp.clip(cell, 0, jnp.maximum(w[r] - 1, 0))
+            gid = jnp.where(avalid, prefE[r] + cell, n)  # global id; pads -> n
 
-    # --- Poisson arrivals over the piecewise-constant-rate line ------------
-    Lam = massE[-1]
-    M = jax.random.poisson(kM, Lam).astype(I64)
-    aM = jnp.minimum(M, acap)
-    v = jax.random.uniform(kV, (acap,), F64) * Lam
-    avalid = jnp.arange(acap, dtype=I64) < aM
-    # Inverse-CDF arrival placement: float mass vector, so the ops wrapper
-    # always takes its XLA fallback here (dtypes never permit int32).
-    r = _locate_prefix(massE, v, R - 1, False)
-    cell = jnp.floor((v - massE[r]) / jnp.maximum(lam[r], _TINY)).astype(I64)
-    cell = jnp.clip(cell, 0, jnp.maximum(w[r] - 1, 0))
-    gid = jnp.where(avalid, prefE[r] + cell, n)  # global cell id; pads -> n
+        # --- dedupe cells (>=1 arrival == one success/failure) -------------
+        with jax.named_scope("exprace_dedupe"):
+            gid = jnp.sort(gid, stable=False)  # plain keys: stability moot
+            uniq = jnp.logical_and(
+                gid < n,
+                jnp.concatenate([jnp.ones((1,), jnp.bool_),
+                                 gid[1:] != gid[:-1]]))
+            seg = _locate_prefix(prefE, gid, R - 1, narrow)
+            # Per-root count, accumulated in int32 (exact: at most acap
+            # arrivals); an int64 scatter-add is several times slower on
+            # the TPU.
+            hits = jnp.zeros((R,), jnp.int32).at[seg].add(
+                uniq.astype(jnp.int32)).astype(I64)
+            k_r = jnp.where(comp, w - hits, hits)  # success count per root
+            outE = jnp.concatenate([jnp.zeros((1,), I64),
+                                    ops.blocked_cumsum(k_r)])
+            K = outE[-1]
 
-    # --- dedupe cells (>=1 arrival == one success/failure) -----------------
-    gid = jnp.sort(gid, stable=False)  # plain keys: stability changes nothing
-    uniq = jnp.logical_and(
-        gid < n, jnp.concatenate([jnp.ones((1,), jnp.bool_), gid[1:] != gid[:-1]])
-    )
-    seg = _locate_prefix(prefE, gid, R - 1, narrow)
-    # Per-root count, accumulated in int32 (exact: at most acap arrivals);
-    # an int64 scatter-add is several times slower on the TPU.
-    hits = jnp.zeros((R,), jnp.int32).at[seg].add(
-        uniq.astype(jnp.int32)).astype(I64)
-    k_r = jnp.where(comp, w - hits, hits)  # success count per root (exact)
-    outE = jnp.concatenate([jnp.zeros((1,), I64), ops.blocked_cumsum(k_r)])
-    K = outE[-1]
+        # --- compact the unique cells, in (segment, cell) order ------------
+        with jax.named_scope("exprace_compact"):
+            ucum = ops.blocked_cumsum(uniq.astype(I64))  # inclusive count
+            urank = ucum - 1                             # global unique rank
+            hitsE = jnp.concatenate([jnp.zeros((1,), I64),
+                                     ops.blocked_cumsum(hits)])
+            local = gid - prefE[seg]                     # offset in segment
+            BIGPAD = jnp.iinfo(jnp.int64).max
+            offE = jnp.concatenate([jnp.zeros((1,), I64),
+                                    ops.blocked_cumsum(w + 1)])
+            lrank = urank - hitsE[seg]                   # rank in segment
+            g_val = local - lrank + offE[seg]            # nondecreasing
+            # Unique cell i sits where ucum first exceeds i: compaction by
+            # search and gather (a scatter of int64 values is slow on the
+            # TPU).
+            slot = jnp.arange(acap, dtype=I64)
+            src = jnp.minimum(ops.searchsorted(ucum, slot), acap - 1)
+            live = slot < ucum[-1]
+            Fc = jnp.where(live, local[src], BIGPAD)     # compacted cells
+            Gc = jnp.where(live, g_val[src], BIGPAD)     # f_i - i + offset
 
-    # --- compact the unique cells, in (segment, cell) order ----------------
-    ucum = ops.blocked_cumsum(uniq.astype(I64))       # inclusive unique count
-    urank = ucum - 1                                  # global unique rank
-    hitsE = jnp.concatenate([jnp.zeros((1,), I64), ops.blocked_cumsum(hits)])
-    local = gid - prefE[seg]                          # cell offset in segment
-    BIGPAD = jnp.iinfo(jnp.int64).max
-    offE = jnp.concatenate([jnp.zeros((1,), I64), ops.blocked_cumsum(w + 1)])
-    lrank = urank - hitsE[seg]                        # rank within segment
-    g_val = local - lrank + offE[seg]                 # globally nondecreasing
-    # Unique cell i sits where ucum first exceeds i: compaction by search
-    # and gather (a scatter of int64 values is slow on the TPU).
-    slot = jnp.arange(acap, dtype=I64)
-    src = jnp.minimum(ops.searchsorted(ucum, slot), acap - 1)
-    live = slot < ucum[-1]
-    Fc = jnp.where(live, local[src], BIGPAD)          # compacted cells
-    Gc = jnp.where(live, g_val[src], BIGPAD)          # f_i - i + segment offset
-
-    # --- emit output slots --------------------------------------------------
-    t = jnp.arange(cap, dtype=I64)
-    tvalid = t < jnp.minimum(K, cap)
-    rO = _locate_prefix(outE, t, R - 1, narrow)
-    l = t - outE[rO]
-    # direct: l-th unique arrival of segment rO
-    direct_pos = Fc[jnp.clip(hitsE[rO] + l, 0, acap - 1)]
-    # complement: l-th missing value among the segment's failures
-    q = l + offE[rO]
-    c = ops.searchsorted(Gc, q) - hitsE[rO]
-    comp_pos = l + jnp.clip(c, 0, jnp.maximum(w[rO] - 1, 0) - l + 1)
-    local_out = jnp.where(comp[rO], comp_pos, direct_pos)
-    positions = prefE[rO] + jnp.clip(local_out, 0, jnp.maximum(w[rO] - 1, 0))
-    overflow = jnp.logical_or(M > acap, K > cap)
-    return _finish(positions, tvalid, n, overflow)
+        # --- emit output slots ----------------------------------------------
+        with jax.named_scope("exprace_emit"):
+            t = jnp.arange(cap, dtype=I64)
+            tvalid = t < jnp.minimum(K, cap)
+            rO = _locate_prefix(outE, t, R - 1, narrow)
+            l = t - outE[rO]
+            # direct: l-th unique arrival of segment rO
+            direct_pos = Fc[jnp.clip(hitsE[rO] + l, 0, acap - 1)]
+            # complement: l-th missing value among the segment's failures
+            q = l + offE[rO]
+            c = ops.searchsorted(Gc, q) - hitsE[rO]
+            comp_pos = l + jnp.clip(c, 0, jnp.maximum(w[rO] - 1, 0) - l + 1)
+            local_out = jnp.where(comp[rO], comp_pos, direct_pos)
+            positions = prefE[rO] + jnp.clip(local_out, 0,
+                                              jnp.maximum(w[rO] - 1, 0))
+            overflow = jnp.logical_or(M > acap, K > cap)
+            return _finish(positions, tvalid, n, overflow)
 
 
 def fused_draw_params(w, p, prefE):

@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.kernels import ops as _kops
 
 from .database import Database
@@ -308,6 +309,11 @@ def pack_index(root: "ShredNode", root_prefE: jnp.ndarray, policy=None
     page-sliced at call time (``PagedArena.from_packed``) when a scoped
     policy shrinks the budget under it.
     """
+    with obs.span("shred_pack"):
+        return _pack_index(root, root_prefE, policy)
+
+
+def _pack_index(root, root_prefE, policy):
     from repro import config  # local: keep shred importable sans config cycle
 
     pol = config.current_policy(policy)
@@ -453,10 +459,13 @@ def _group_child(
     of the 2-pass USR grouping, unified (DESIGN.md §3) — now a composition
     of the three reusable passes ``reshred_incremental`` also merges into
     (DESIGN.md §11)."""
-    kp, kc = _edge_keys(parent_rel, parent_vars, child)
-    order, kc_sorted, cumw_excl = _sorted_group(kc, child_weight)
-    child_hd, child_start, child_len, child_w, nxt = _link_columns(
-        kp, kc_sorted, order, cumw_excl, rep)
+    with obs.span("shred_keys"):
+        kp, kc = _edge_keys(parent_rel, parent_vars, child)
+    with obs.span("shred_sort"):
+        order, kc_sorted, cumw_excl = _sorted_group(kc, child_weight)
+    with obs.span("shred_link"):
+        child_hd, child_start, child_len, child_w, nxt = _link_columns(
+            kp, kc_sorted, order, cumw_excl, rep)
     return child_hd, child_start, child_len, child_w, nxt, order, cumw_excl
 
 
@@ -485,21 +494,24 @@ def _build_node(
     for child, child_weight in children:
         hd, st, ln, w, nxt, perm, cume = _group_child(
             rel, variables, child, child_weight, rep)
-        hds.append(jnp.asarray(hd))
-        starts.append(jnp.asarray(st))
-        lens.append(jnp.asarray(ln))
-        ws.append(jnp.asarray(w))
-        new_children.append(dataclasses.replace(
-            child, nxt=None if nxt is None else jnp.asarray(nxt),
-            perm=jnp.asarray(perm), cumw_excl=jnp.asarray(cume)))
+        with obs.span("shred_place"):
+            hds.append(jnp.asarray(hd))
+            starts.append(jnp.asarray(st))
+            lens.append(jnp.asarray(ln))
+            ws.append(jnp.asarray(w))
+            new_children.append(dataclasses.replace(
+                child, nxt=None if nxt is None else jnp.asarray(nxt),
+                perm=jnp.asarray(perm), cumw_excl=jnp.asarray(cume)))
         weight = weight * w  # zero-weight propagation == semijoin reduction
 
+    with obs.span("shred_place"):
+        dev_weight = jnp.asarray(weight)
     node = ShredNode(
         name=tnode.atom.name,
         variables=variables,
         owned=owned,
         data=rel.project(tuple(dict.fromkeys(variables))),
-        weight=jnp.asarray(weight),
+        weight=dev_weight,
         children=tuple(new_children),
         child_hd=tuple(hds),
         child_start=tuple(starts),
@@ -517,8 +529,10 @@ def root_prefix(weight) -> jnp.ndarray:
     if total >= MAX_JOIN:
         raise ValueError(f"join size {total} is beyond the supported "
                          f"2^53 (shred.MAX_JOIN)")
-    return jnp.asarray(np.concatenate(
-        [np.zeros((1,), np.int64), np.cumsum(w, dtype=np.int64)]))
+    prefix = np.concatenate(
+        [np.zeros((1,), np.int64), np.cumsum(w, dtype=np.int64)])
+    with obs.span("shred_place"):
+        return jnp.asarray(prefix)
 
 
 def build_shred(db: Database, query: JoinQuery, rep: str = "usr") -> Shred:
@@ -530,10 +544,11 @@ def build_shred(db: Database, query: JoinQuery, rep: str = "usr") -> Shred:
     """
     if rep not in ("csr", "usr", "both"):
         raise ValueError(f"rep must be csr|usr|both, got {rep!r}")
-    plan = build_plan(query)
-    root, weight = _build_node(plan, db, rep, frozenset())
-    prefE = root_prefix(weight)
-    packed, paged = pack_index(root, prefE)
+    with obs.span("shred_build"):
+        plan = build_plan(query)
+        root, weight = _build_node(plan, db, rep, frozenset())
+        prefE = root_prefix(weight)
+        packed, paged = pack_index(root, prefE)
     return Shred(root=root, root_prefE=prefE, rep=rep,
                  packed=packed, paged=paged)
 

@@ -36,7 +36,7 @@ from typing import Dict, Optional, Tuple, Union
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import config
+from repro import config, obs
 from repro.core.database import Database
 from repro.core.distributed import (
     StackedShred, build_stacked, place_stacked, reshard_incremental,
@@ -399,11 +399,12 @@ class QueryEngine:
                                   else None)
         if spec.mesh is not None:
             plan = self.compile_sharded(query, spec.mesh, spec)
-            if isinstance(plan, ShardedPlan):
-                return plan.full_join()
         else:
             plan = self.compile(query, spec)
-        return plan.full_join(rep=spec.rep)
+        with obs.span("engine_full_join"):
+            if isinstance(plan, ShardedPlan):
+                return plan.full_join()
+            return plan.full_join(rep=spec.rep)
 
     def poisson_sample(self, query: JoinQuery, key,
                        spec: Optional[DrawSpec] = None, *,
@@ -433,19 +434,17 @@ class QueryEngine:
         if query.prob_var is None:
             raise ValueError("Poisson sampling needs query.prob_var (beta_y)")
         if spec.mesh is not None:
+            # a degenerate mesh gives the single-device CompiledPlan
             plan = self.compile_sharded(query, spec.mesh, spec)
-            if isinstance(plan, ShardedPlan):
-                if auto:
-                    return plan.sample_auto(key, cap=spec.cap, acap=spec.acap)
-                return plan.sample(key, cap=spec.cap, acap=spec.acap)
-            # degenerate mesh: compile_sharded already fell back to the
-            # single-device CompiledPlan — reuse it, don't compile twice
         else:
             plan = self.compile(query, spec)
-        if auto:
-            return plan.sample_auto(key, cap=spec.cap, acap=spec.acap)
-        return plan.sample(key, cap=spec.cap, acap=spec.acap,
-                           rep=spec.rep if spec.rep != "both" else None)
+        with obs.span("engine_sample"):
+            if auto:
+                return plan.sample_auto(key, cap=spec.cap, acap=spec.acap)
+            if isinstance(plan, ShardedPlan):
+                return plan.sample(key, cap=spec.cap, acap=spec.acap)
+            return plan.sample(key, cap=spec.cap, acap=spec.acap,
+                               rep=spec.rep if spec.rep != "both" else None)
 
     # ``sample`` is the preferred name for the Poisson entry point; kwargs
     # (including ``spec=`` and ``mesh=``) are identical.
@@ -482,14 +481,16 @@ class QueryEngine:
         if query.prob_var is None:
             raise ValueError("Poisson sampling needs query.prob_var (beta_y)")
         if spec.mesh is not None:
+            # a degenerate mesh gives the single-device CompiledPlan
             plan = self.compile_sharded(query, spec.mesh, spec)
-            if isinstance(plan, ShardedPlan):
-                return plan.sample_batch(keys, cap=spec.cap, acap=spec.acap)
-            # degenerate mesh: fall through to the single-device plan
         else:
             plan = self.compile(query, spec)
-        return plan.sample_batch(keys, cap=spec.cap, acap=spec.acap,
-                                 rep=spec.rep if spec.rep != "both" else None)
+        with obs.span("engine_sample_batch"):
+            if isinstance(plan, ShardedPlan):
+                return plan.sample_batch(keys, cap=spec.cap, acap=spec.acap)
+            return plan.sample_batch(
+                keys, cap=spec.cap, acap=spec.acap,
+                rep=spec.rep if spec.rep != "both" else None)
 
     def uniform_sample(self, query: JoinQuery, key, p: float, *,
                        spec: Optional[DrawSpec] = None,

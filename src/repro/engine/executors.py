@@ -63,7 +63,8 @@ def _sample_jit(
     else:
         raise ValueError(f"unknown jit sampling method {method!r}")
     if route not in ("fused", "reference", "paged"):
-        pos = jnp.minimum(ps.positions, jnp.maximum(prefE[-1] - 1, 0))  # clamp
+        with jax.named_scope("usr_locate"):  # clamped for the locate
+            pos = jnp.minimum(ps.positions, jnp.maximum(prefE[-1] - 1, 0))
         cols = probe.get(shred, pos, rep=rep)
     if project is not None:
         cols = {v: c for v, c in cols.items() if v in project}

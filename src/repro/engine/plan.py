@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import estimate, probe, sampling
 from repro.core.jointree import JoinQuery
 from repro.core.poisson import JoinSample
@@ -27,7 +28,18 @@ from . import executors
 from .capacity import CapacityPolicy, DEFAULT_POLICY
 from .spec import DrawSpec
 
-__all__ = ["CompiledPlan", "redraw_with_doubling"]
+__all__ = ["CompiledPlan", "redraw_with_doubling", "draw_call"]
+
+
+def draw_call(route: str, cap: int, acap: int, draws: int, lanes: int):
+    """The span of one draw executor call, with the route and capacities
+    it runs at, and the call's draw counters (DESIGN.md §16): ``draws``
+    draws, and ``acap`` arrival lanes for each of the ``lanes`` lanes the
+    executor computes (a batch's padding lanes and every shard included)."""
+    obs.count("draws", draws)
+    obs.count("draw_arrival_lanes", acap * lanes)
+    return obs.span("plan_draw", route=route, cap=cap, acap=acap,
+                    batch=draws)
 
 
 def redraw_with_doubling(draw, cap: int, acap: int, max_doublings: int):
@@ -84,7 +96,8 @@ class CompiledPlan:
     def __post_init__(self):
         self._default_cap = None
         self._arrival_cap = None
-        self._bind_shred(self.shred)
+        with obs.span("plan_bind"):
+            self._bind_shred(self.shred)
         self._jit = executors.sample_executor(self.method, self.project)
         self._batched_jit = executors.batched_sample_executor(
             self.method, self.project)
@@ -161,7 +174,8 @@ class CompiledPlan:
         keeping the jitted executors — and with them every cached trace.
         A delta that preserves array shapes therefore costs zero retraces
         on the next warm draw (DESIGN.md §11)."""
-        self._bind_shred(shred)
+        with obs.span("plan_bind"):
+            self._bind_shred(shred)
         return self
 
     @property
@@ -217,11 +231,13 @@ class CompiledPlan:
         # draws from its own stream, so honoring the rep request means
         # honoring the per-node sampler with it.
         route = "pernode" if rep else self._route
-        return self._jit(self.shred, self.w, self.p, self.prefE, key, cap=cap,
-                         rep=rep or self.rep_default, n=n, acap=acap,
-                         narrow=self._narrow, route=route,
-                         dparams=self._dparams if route != "pernode" else None,
-                         massE=self._massE)
+        with draw_call(route, cap, acap, 1, 1):
+            return self._jit(
+                self.shred, self.w, self.p, self.prefE, key, cap=cap,
+                rep=rep or self.rep_default, n=n, acap=acap,
+                narrow=self._narrow, route=route,
+                dparams=self._dparams if route != "pernode" else None,
+                massE=self._massE)
 
     def sample_batch(self, keys, cap: Optional[int] = None,
                      rep: Optional[str] = None,
@@ -248,12 +264,13 @@ class CompiledPlan:
         n = self.join_size if self.method == "ptbern_flat" else 0
         kpad, _ = executors.pad_batch_keys(keys)
         route = "pernode" if rep else self._route  # explicit rep pins pernode
-        smp = self._batched_jit(self.shred, self.w, self.p, self.prefE, kpad,
-                                cap=cap, rep=rep or self.rep_default, n=n,
-                                acap=acap, narrow=self._narrow, route=route,
-                                dparams=(self._dparams
-                                         if route != "pernode" else None),
-                                massE=self._massE)
+        with draw_call(route, cap, acap, batch, int(kpad.shape[0])):
+            smp = self._batched_jit(
+                self.shred, self.w, self.p, self.prefE, kpad, cap=cap,
+                rep=rep or self.rep_default, n=n, acap=acap,
+                narrow=self._narrow, route=route,
+                dparams=self._dparams if route != "pernode" else None,
+                massE=self._massE)
         if int(kpad.shape[0]) != batch:
             smp = jax.tree.map(lambda x: x[:batch], smp)
         return smp

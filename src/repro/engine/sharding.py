@@ -38,7 +38,7 @@ from repro.core.poisson import JoinSample
 
 from . import executors
 from .capacity import CapacityPolicy, DEFAULT_POLICY
-from .plan import redraw_with_doubling
+from .plan import draw_call, redraw_with_doubling
 from .spec import DrawSpec
 
 __all__ = ["ShardPlan", "ShardedPlan", "plan_shards", "BATCH_AXES"]
@@ -321,8 +321,10 @@ class ShardedPlan:
         if self.stacked.p is None:
             raise ValueError("plan has no prob_var; use full_join")
         st = self.stacked
-        return self._sampler(cap or self.cap, acap or self.acap)(
-            st.shred, st.w, st.p, st.prefE, self.massE, key)
+        cap, acap = cap or self.cap, acap or self.acap
+        with draw_call("sharded", cap, acap, 1, self.num_shards):
+            return self._sampler(cap, acap)(
+                st.shred, st.w, st.p, st.prefE, self.massE, key)
 
     def _call_overrides(self, spec: Optional[DrawSpec], cap, acap):
         """Per-call ``DrawSpec`` under the explicit kwargs (kwargs win).
@@ -399,9 +401,11 @@ class ShardedPlan:
                                                 cap or self.cap, batch)
         kpad, _ = executors.pad_batch_keys(keys)
         st = self.stacked
-        smp, _totals = self._batched_sampler(cap or self.cap,
-                                             acap or self.acap)(
-            st.shred, st.w, st.p, st.prefE, self.massE, kpad)
+        cap, acap = cap or self.cap, acap or self.acap
+        with draw_call("sharded", cap, acap, batch,
+                       int(kpad.shape[0]) * self.num_shards):
+            smp, _totals = self._batched_sampler(cap, acap)(
+                st.shred, st.w, st.p, st.prefE, self.massE, kpad)
         # Host gather per lane (padding lanes never gathered), then stack.
         columns = {v: np.asarray(a) for v, a in smp.columns.items()}
         positions = np.asarray(smp.positions)   # (S, Bp, cap)

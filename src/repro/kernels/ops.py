@@ -108,37 +108,40 @@ def kary_searchsorted(table: jnp.ndarray, q: jnp.ndarray,
     no entry of a later row qualifies. Queries run ``chunk`` at a time."""
     if side not in ("left", "right"):
         raise ValueError(f"side must be 'left' or 'right', got {side!r}")
-    n = table.shape[0]
-    dt = jnp.promote_types(table.dtype, q.dtype)
-    table, q = table.astype(dt), q.astype(dt)
-    big = (jnp.inf if jnp.issubdtype(dt, jnp.floating)
-           else jnp.iinfo(dt).max)
-    levels, cur = [], table
-    while True:
-        rows = max(-(-cur.shape[0] // fan), 1)
-        lv = jnp.pad(cur, (0, rows * fan - cur.shape[0]),
-                     constant_values=big).reshape(rows, fan)
-        levels.append(lv)
-        if rows == 1:
-            break
-        cur = lv[:, 0]
-    levels.reverse()
+    with jax.named_scope("search"):
+        n = table.shape[0]
+        dt = jnp.promote_types(table.dtype, q.dtype)
+        table, q = table.astype(dt), q.astype(dt)
+        big = (jnp.inf if jnp.issubdtype(dt, jnp.floating)
+               else jnp.iinfo(dt).max)
+        levels, cur = [], table
+        while True:
+            rows = max(-(-cur.shape[0] // fan), 1)
+            lv = jnp.pad(cur, (0, rows * fan - cur.shape[0]),
+                         constant_values=big).reshape(rows, fan)
+            levels.append(lv)
+            if rows == 1:
+                break
+            cur = lv[:, 0]
+        levels.reverse()
 
-    def descend(qc):
-        c = jnp.zeros(qc.shape, jnp.int32)
-        for i, lv in enumerate(levels):
-            row = lv[c]
-            hit = row <= qc[:, None] if side == "right" else row < qc[:, None]
-            cnt = jnp.sum(hit, axis=1, dtype=jnp.int32)
-            c = c * fan + (cnt if i == len(levels) - 1
-                           else jnp.maximum(cnt - 1, 0))
-        return jnp.minimum(c, n)  # pads (dtype max) count only for q == max
+        def descend(qc):
+            c = jnp.zeros(qc.shape, jnp.int32)
+            for i, lv in enumerate(levels):
+                row = lv[c]
+                hit = (row <= qc[:, None] if side == "right"
+                       else row < qc[:, None])
+                cnt = jnp.sum(hit, axis=1, dtype=jnp.int32)
+                c = c * fan + (cnt if i == len(levels) - 1
+                               else jnp.maximum(cnt - 1, 0))
+            # pads (dtype max) count only for q == max
+            return jnp.minimum(c, n)
 
-    k = q.shape[0]
-    chunk = min(chunk, max(k, 1))
-    kp = -(-k // chunk) * chunk
-    qp = jnp.pad(q, (0, kp - k)).reshape(-1, chunk)
-    return jax.lax.map(descend, qp).reshape(-1)[:k]
+        k = q.shape[0]
+        chunk = min(chunk, max(k, 1))
+        kp = -(-k // chunk) * chunk
+        qp = jnp.pad(q, (0, kp - k)).reshape(-1, chunk)
+        return jax.lax.map(descend, qp).reshape(-1)[:k]
 
 
 def searchsorted(table: jnp.ndarray, q: jnp.ndarray,
@@ -149,7 +152,8 @@ def searchsorted(table: jnp.ndarray, q: jnp.ndarray,
     elsewhere."""
     if config.backend() == "tpu":
         return kary_searchsorted(table, q, side)
-    return jnp.searchsorted(table, q, side=side)
+    with jax.named_scope("search"):
+        return jnp.searchsorted(table, q, side=side)
 
 
 def searchsorted_prefix(pref: jnp.ndarray, q: jnp.ndarray,
@@ -166,15 +170,16 @@ def searchsorted_prefix(pref: jnp.ndarray, q: jnp.ndarray,
     """
     n = q.shape[0]
     pol = config.current_policy(policy)
-    if (pref.dtype != jnp.int32 or q.dtype != jnp.int32
-            or pref.shape[0] > min(_BSEARCH_MAX_TABLE, pol.vmem_limit)
-            or not pallas_enabled(pol)):
-        return jnp.maximum(searchsorted(pref, q) - 1, 0)
-    tiles = to_tiles(q)
-    out = _bsearch_tiles(pref, tiles,
-                         block_rows=tile_for("bsearch_probe", n, pol),
-                         interpret=_interpret(interpret, pol))
-    return out.reshape(-1)[:n]
+    with jax.named_scope("search"):
+        if (pref.dtype != jnp.int32 or q.dtype != jnp.int32
+                or pref.shape[0] > min(_BSEARCH_MAX_TABLE, pol.vmem_limit)
+                or not pallas_enabled(pol)):
+            return jnp.maximum(searchsorted(pref, q) - 1, 0)
+        tiles = to_tiles(q)
+        out = _bsearch_tiles(pref, tiles,
+                             block_rows=tile_for("bsearch_probe", n, pol),
+                             interpret=_interpret(interpret, pol))
+        return out.reshape(-1)[:n]
 
 
 def blocked_cumsum(x: jnp.ndarray, block: int = 1024) -> jnp.ndarray:

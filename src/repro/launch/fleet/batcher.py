@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
+
 __all__ = ["JoinSampleRequest", "UpdateRequest", "MicroBatcher",
            "serve_join_samples"]
 
@@ -141,12 +143,26 @@ class MicroBatcher:
         for r in batch:
             groups.setdefault(query_fingerprint(r.query), []).append(r)
         version = getattr(self.engine.db, "version", 0)
-        for reqs in groups.values():
+        with obs.span("batcher_flush", flush=self.flushes + 1,
+                      lanes=len(batch), shapes=len(groups)):
+            for reqs in groups.values():
+                self._dispatch(reqs, version)
+        self.flushes += 1
+        self.served += len(batch)
+        return batch
+
+    def _dispatch(self, reqs: List[JoinSampleRequest], version: int):
+        """One batched draw for requests of one query shape, its results
+        routed back onto the requests."""
+        with obs.span("batcher_keys"):
             keys = jnp.stack([jax.random.key(r.seed) for r in reqs])
+        with obs.span("batcher_dispatch"):
             smp = self.engine.sample_batch(reqs[0].query, keys,
                                            mesh=self.mesh, axes=self.axes)
+        with obs.span("batcher_wait"):
             jax.block_until_ready(smp.count)
-            done_t = self.clock()
+        done_t = self.clock()
+        with obs.span("batcher_copy_out"):
             counts = np.asarray(smp.count)
             overflow = np.asarray(smp.overflow)
             cols = ({c: np.asarray(v) for c, v in smp.columns.items()}
@@ -159,10 +175,7 @@ class MicroBatcher:
                 if cols is not None:
                     r.rows = {c: v[lane, : r.count].copy()
                               for c, v in cols.items()}
-            self.dispatches += 1
-        self.flushes += 1
-        self.served += len(batch)
-        return batch
+        self.dispatches += 1
 
 
 def serve_join_samples(engine, requests: List, mesh=None,
