@@ -188,24 +188,20 @@ def paged_available(shred: Shred, policy=None) -> bool:
             and layout.size <= config.PAGED_PACK_LIMIT)
 
 
-def select_rep(shred: Shred, base: str, policy=None) -> Tuple[str, bool]:
-    """The executor policy both plan layers share (DESIGN.md §4): given the
-    rep a plan would use (``usr``/``csr``), return ``(rep, narrow)`` —
-    upgrade USR down the kernel ladder (``usr_fused``, then ``usr_paged``
-    when only the pages fit the VMEM budget) and enable int32-narrowed
-    sampler searches iff the shred packed an int32 index (monolithic or
-    paged) AND the backend prefers Pallas (compiled mode /
+def select_rep(shred: Shred, base: str, policy=None) -> str:
+    """The executor rep both plan layers share (DESIGN.md §4): given the
+    rep a plan would use (``usr``/``csr``), upgrade USR down the kernel
+    ladder (``usr_fused``, then ``usr_paged`` when only the pages fit the
+    VMEM budget) when the backend prefers Pallas (compiled mode /
     ``REPRO_PALLAS_PREFER=1``). Single source of truth so single-device
     and sharded plans cannot diverge."""
     pol = config.current_policy(policy)
-    prefer = pol.preferred
-    narrow = (shred.packed is not None or shred.paged is not None) and prefer
-    if base == "usr" and prefer:
+    if base == "usr" and pol.preferred:
         if fused_available(shred, pol):
-            return "usr_fused", narrow
+            return "usr_fused"
         if paged_available(shred, pol):
-            return "usr_paged", narrow
-    return base, narrow
+            return "usr_paged"
+    return base
 
 
 def _require(kernel: str, policy=None) -> None:

@@ -62,6 +62,7 @@ __all__ = [
     "hybrid_positions",
     "exprace_positions",
     "exprace_mass",
+    "exprace_fits_int32",
     "pt_bern_flat_positions",
     "fused_draw_params",
     "pt_positions_host",
@@ -170,17 +171,29 @@ def hybrid_positions(key, p, n: int, cap: int) -> PositionSample:
 # Non-uniform (Poisson) position sampling over root groups
 # ---------------------------------------------------------------------------
 
-def _locate_prefix(prefE, q, hi, narrow: bool):
-    """clip(searchsorted(prefE, q, 'right') - 1, 0, hi) — routed through the
-    Pallas branchless-descent kernel (``ops.searchsorted_prefix``) on
-    int32-narrowed views when the caller statically guarantees every value
-    fits int32 (``narrow=True``: the compiled plan knows join_size < 2^31
-    because the shred packed its fused arena — DESIGN.md §4). Bit-identical
-    to the XLA expression either way; float prefixes (EXPRACE's mass
-    vector) take ``ops``' XLA fallback — dtypes there never permit."""
-    if narrow:
-        prefE, q = prefE.astype(jnp.int32), q.astype(jnp.int32)
-    return jnp.minimum(ops.searchsorted_prefix(prefE, q), hi).astype(I64)
+def _locate_prefix(pref, q, hi: int, dtype):
+    """clip(searchsorted(pref, q, 'right') - 1, 0, hi), as ``dtype``.
+    Where the backend prefers Pallas, ``ops.searchsorted_prefix`` takes
+    its compare-and-count kernel for a small int32 table and the XLA
+    search for any other; elsewhere the XLA search, which is what the
+    kernel computes (DESIGN.md §4)."""
+    if ops.pallas_preferred():
+        j = ops.searchsorted_prefix(pref, q)
+    else:
+        j = jnp.maximum(ops.searchsorted(pref, q) - 1, 0)
+    return jnp.minimum(j, hi).astype(dtype)
+
+
+def exprace_fits_int32(n: int, R: int, acap: int, cap: int = 0) -> bool:
+    """The host certificate for EXPRACE's int32 program (DESIGN.md §4):
+    true iff every integer the draw keeps fits int32 with room for its
+    padding value, for a join of ``n`` tuples over ``R`` root rows at
+    arrival capacity ``acap`` and sample capacity ``cap``. The largest
+    such integer is a complement query, below ``offE[-1] = n + R``; the
+    pads are the dtype's max, and ranks and slots lie below the
+    capacities."""
+    top = (1 << 31) - 1
+    return n + R + 1 <= top and max(acap, cap) <= top
 
 
 def _exprace_rates(p, xp=jnp):
@@ -227,11 +240,18 @@ def exprace_positions(
     arrival_cap: scratch capacity for raw Poisson arrivals (default: cap;
         needs >= ln2/min(p,1-p)-adjusted slack — see estimate.plan_capacity)
     narrow: static caller guarantee that every integer prefix value fits
-        int32, enabling the Pallas searchsorted kernel (``_locate_prefix``);
-        must not change results (it does not — same clip semantics).
+        int32 (``exprace_fits_int32``): every per-lane integer vector, its
+        searches, gathers and sort then run in int32, where the TPU
+        emulates int64 as two u32 halves. The float64 arrival arithmetic
+        is the same either way, and so are the results, bit for bit:
+        positions are widened to int64 on the way out. Only values the
+        draw discards (lanes past its count, a complement root's direct
+        slot) can wrap in int32.
     """
     acap = arrival_cap or cap
     R = w.shape[0]
+    idx = jnp.int32 if narrow else I64
+    w, prefE = w.astype(idx), prefE.astype(idx)
     n = prefE[-1]
     with jax.named_scope("exprace"):
         # --- Poisson arrivals over the piecewise-constant-rate line --------
@@ -240,16 +260,13 @@ def exprace_positions(
             comp, lam = _exprace_rates(p)
             Lam = massE[-1]
             M = jax.random.poisson(kM, Lam).astype(I64)
-            aM = jnp.minimum(M, acap)
+            aM = jnp.minimum(M, acap).astype(idx)
             v = jax.random.uniform(kV, (acap,), F64) * Lam
-            avalid = jnp.arange(acap, dtype=I64) < aM
-            # Inverse-CDF arrival placement: float mass vector, so the ops
-            # wrapper always takes its XLA fallback here (dtypes never
-            # permit int32).
-            r = _locate_prefix(massE, v, R - 1, False)
-            cell = jnp.floor((v - massE[r])
-                             / jnp.maximum(lam[r], _TINY)).astype(I64)
-            cell = jnp.clip(cell, 0, jnp.maximum(w[r] - 1, 0))
+            avalid = jnp.arange(acap, dtype=idx) < aM
+            # Inverse-CDF arrival placement over the float mass vector.
+            r = _locate_prefix(massE, v, R - 1, idx)
+            cell = jnp.floor((v - massE[r]) / jnp.maximum(lam[r], _TINY))
+            cell = jnp.clip(cell, 0, jnp.maximum(w[r] - 1, 0)).astype(idx)
             gid = jnp.where(avalid, prefE[r] + cell, n)  # global id; pads -> n
 
         # --- dedupe cells (>=1 arrival == one success/failure) -------------
@@ -259,33 +276,33 @@ def exprace_positions(
                 gid < n,
                 jnp.concatenate([jnp.ones((1,), jnp.bool_),
                                  gid[1:] != gid[:-1]]))
-            seg = _locate_prefix(prefE, gid, R - 1, narrow)
+            seg = _locate_prefix(prefE, gid, R - 1, idx)
             # Per-root count, accumulated in int32 (exact: at most acap
             # arrivals); an int64 scatter-add is several times slower on
             # the TPU.
             hits = jnp.zeros((R,), jnp.int32).at[seg].add(
-                uniq.astype(jnp.int32)).astype(I64)
+                uniq.astype(jnp.int32)).astype(idx)
             k_r = jnp.where(comp, w - hits, hits)  # success count per root
-            outE = jnp.concatenate([jnp.zeros((1,), I64),
+            outE = jnp.concatenate([jnp.zeros((1,), idx),
                                     ops.blocked_cumsum(k_r)])
-            K = outE[-1]
+            K = outE[-1].astype(I64)
 
         # --- compact the unique cells, in (segment, cell) order ------------
         with jax.named_scope("exprace_compact"):
-            ucum = ops.blocked_cumsum(uniq.astype(I64))  # inclusive count
+            ucum = ops.blocked_cumsum(uniq.astype(idx))  # inclusive count
             urank = ucum - 1                             # global unique rank
-            hitsE = jnp.concatenate([jnp.zeros((1,), I64),
+            hitsE = jnp.concatenate([jnp.zeros((1,), idx),
                                      ops.blocked_cumsum(hits)])
             local = gid - prefE[seg]                     # offset in segment
-            BIGPAD = jnp.iinfo(jnp.int64).max
-            offE = jnp.concatenate([jnp.zeros((1,), I64),
+            BIGPAD = jnp.iinfo(idx).max
+            offE = jnp.concatenate([jnp.zeros((1,), idx),
                                     ops.blocked_cumsum(w + 1)])
             lrank = urank - hitsE[seg]                   # rank in segment
             g_val = local - lrank + offE[seg]            # nondecreasing
             # Unique cell i sits where ucum first exceeds i: compaction by
             # search and gather (a scatter of int64 values is slow on the
             # TPU).
-            slot = jnp.arange(acap, dtype=I64)
+            slot = jnp.arange(acap, dtype=idx)
             src = jnp.minimum(ops.searchsorted(ucum, slot), acap - 1)
             live = slot < ucum[-1]
             Fc = jnp.where(live, local[src], BIGPAD)     # compacted cells
@@ -293,9 +310,9 @@ def exprace_positions(
 
         # --- emit output slots ----------------------------------------------
         with jax.named_scope("exprace_emit"):
-            t = jnp.arange(cap, dtype=I64)
-            tvalid = t < jnp.minimum(K, cap)
-            rO = _locate_prefix(outE, t, R - 1, narrow)
+            t = jnp.arange(cap, dtype=idx)
+            tvalid = t < jnp.minimum(K, cap).astype(idx)
+            rO = _locate_prefix(outE, t, R - 1, idx)
             l = t - outE[rO]
             # direct: l-th unique arrival of segment rO
             direct_pos = Fc[jnp.clip(hitsE[rO] + l, 0, acap - 1)]

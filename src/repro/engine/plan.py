@@ -28,18 +28,38 @@ from . import executors
 from .capacity import CapacityPolicy, DEFAULT_POLICY
 from .spec import DrawSpec
 
-__all__ = ["CompiledPlan", "redraw_with_doubling", "draw_call"]
+__all__ = ["CompiledPlan", "redraw_with_doubling", "draw_call",
+           "resolve_narrow"]
 
 
-def draw_call(route: str, cap: int, acap: int, draws: int, lanes: int):
-    """The span of one draw executor call, with the route and capacities
-    it runs at, and the call's draw counters (DESIGN.md §16): ``draws``
-    draws, and ``acap`` arrival lanes for each of the ``lanes`` lanes the
-    executor computes (a batch's padding lanes and every shard included)."""
+def draw_call(route: str, cap: int, acap: int, draws: int, lanes: int,
+              narrow: bool):
+    """The span of one draw executor call, with the route, capacities and
+    sampler width it runs at, and the call's draw counters (DESIGN.md
+    §16): ``draws`` draws, ``draws_int32`` of them when EXPRACE runs in
+    int32 (``narrow``), and ``acap`` arrival lanes for each of the
+    ``lanes`` lanes the executor computes (a batch's padding lanes and
+    every shard included)."""
     obs.count("draws", draws)
+    if narrow:
+        obs.count("draws_int32", draws)
     obs.count("draw_arrival_lanes", acap * lanes)
     return obs.span("plan_draw", route=route, cap=cap, acap=acap,
-                    batch=draws)
+                    batch=draws, narrow=narrow)
+
+
+def resolve_narrow(override: Optional[bool], n: int, R: int,
+                   acap: int) -> bool:
+    """A plan's EXPRACE width (DESIGN.md §4): the join-size certificate
+    (``sampling.exprace_fits_int32``) under the spec's ``narrow``
+    override — None takes the certificate's verdict, False turns int32
+    off, and True requires the certificate."""
+    fits = sampling.exprace_fits_int32(n, R, acap)
+    if override and not fits:
+        raise ValueError(
+            f"DrawSpec(narrow=True) needs a join that fits int32: "
+            f"n + R + 1 = {n + R + 1} and acap = {acap} must be below 2^31")
+    return fits if override is None else override
 
 
 def redraw_with_doubling(draw, cap: int, acap: int, max_doublings: int):
@@ -79,7 +99,7 @@ class CompiledPlan:
     policy: CapacityPolicy = DEFAULT_POLICY
     # ``rep_default`` (the concrete rep used when a call passes None) and
     # ``_narrow`` are derived per bound shred in ``_bind_shred`` — see
-    # probe.select_rep (DESIGN.md §4).
+    # probe.select_rep and resolve_narrow (DESIGN.md §4).
 
     @property
     def rep(self) -> str:
@@ -102,18 +122,6 @@ class CompiledPlan:
         self._batched_jit = executors.batched_sample_executor(
             self.method, self.project)
 
-    def _resolve_narrow(self, shred: Shred, auto_narrow: bool) -> bool:
-        """Apply the spec's narrowing override to the auto verdict.
-        Forcing ``narrow=True`` needs a packed (int32-safe) index — the
-        arena's existence is the exactness proof (DESIGN.md §4)."""
-        if self.spec.narrow is None:
-            return auto_narrow
-        if self.spec.narrow and shred.packed is None and shred.paged is None:
-            raise ValueError(
-                "DrawSpec(narrow=True) requires a packed int32 index "
-                "(join < 2^31, no empty node); this shred has none")
-        return self.spec.narrow
-
     def _bind_shred(self, shred: Shred) -> None:
         root = shred.root
         self.shred = shred
@@ -123,13 +131,11 @@ class CompiledPlan:
         # capacity path, and int(device_scalar) is a blocking sync — per
         # dispatch it would stall the async prefetch ring (DESIGN.md §13).
         self._join_size = int(shred.join_size)
-        # Executor rep + int32-narrowing selection (probe.select_rep,
-        # DESIGN.md §4). Recomputed on every (re)bind: an upgraded index
-        # may gain or lose its arena (int32 narrowing is per-snapshot).
+        # Executor rep (probe.select_rep, DESIGN.md §4). Recomputed on
+        # every (re)bind: an upgraded index may gain or lose its arena.
         # Explicit per-call rep overrides still win in sample()/full_join().
-        self.rep_default, auto_narrow = probe.select_rep(
+        self.rep_default = probe.select_rep(
             shred, "usr" if self.rep == "both" else self.rep)
-        self._narrow = self._resolve_narrow(shred, auto_narrow)
         if self.query.prob_var is not None:
             if self.query.prob_var not in root.variables:
                 raise AssertionError("build_plan must reroot prob_var to the root")
@@ -168,6 +174,11 @@ class CompiledPlan:
             self._route = "pernode"
             self._dparams = None
             self._massE = None
+        # EXPRACE's width, per snapshot: a delta can grow the join past
+        # the int32 certificate.
+        self._narrow = resolve_narrow(self.spec.narrow, self._join_size,
+                                      int(self.w.shape[0]),
+                                      self._arrival_cap or 0)
 
     def rebind_shred(self, shred: Shred) -> "CompiledPlan":
         """Swap in an (incrementally upgraded) index for a newer snapshot,
@@ -185,8 +196,18 @@ class CompiledPlan:
 
     @property
     def narrow(self) -> bool:
-        """Whether the sampler's integer searches run int32-narrowed."""
+        """Whether EXPRACE's integer pipeline runs in int32 (the bound
+        verdict of ``resolve_narrow``)."""
         return self._narrow
+
+    def _narrow_call(self, route: str, cap: int, acap: int) -> bool:
+        """Whether one call runs EXPRACE in int32: the bound verdict, on
+        the per-node route, at capacities the certificate still covers
+        (a redraw doubles them)."""
+        return (self._narrow and route == "pernode"
+                and self.method == "exprace"
+                and sampling.exprace_fits_int32(
+                    self._join_size, int(self.w.shape[0]), acap, cap))
 
     # -- capacity planning ---------------------------------------------------
     @property
@@ -231,11 +252,12 @@ class CompiledPlan:
         # draws from its own stream, so honoring the rep request means
         # honoring the per-node sampler with it.
         route = "pernode" if rep else self._route
-        with draw_call(route, cap, acap, 1, 1):
+        narrow = self._narrow_call(route, cap, acap)
+        with draw_call(route, cap, acap, 1, 1, narrow):
             return self._jit(
                 self.shred, self.w, self.p, self.prefE, key, cap=cap,
                 rep=rep or self.rep_default, n=n, acap=acap,
-                narrow=self._narrow, route=route,
+                narrow=narrow, route=route,
                 dparams=self._dparams if route != "pernode" else None,
                 massE=self._massE)
 
@@ -264,11 +286,12 @@ class CompiledPlan:
         n = self.join_size if self.method == "ptbern_flat" else 0
         kpad, _ = executors.pad_batch_keys(keys)
         route = "pernode" if rep else self._route  # explicit rep pins pernode
-        with draw_call(route, cap, acap, batch, int(kpad.shape[0])):
+        narrow = self._narrow_call(route, cap, acap)
+        with draw_call(route, cap, acap, batch, int(kpad.shape[0]), narrow):
             smp = self._batched_jit(
                 self.shred, self.w, self.p, self.prefE, kpad, cap=cap,
                 rep=rep or self.rep_default, n=n, acap=acap,
-                narrow=self._narrow, route=route,
+                narrow=narrow, route=route,
                 dparams=self._dparams if route != "pernode" else None,
                 massE=self._massE)
         if int(kpad.shape[0]) != batch:

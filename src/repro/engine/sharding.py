@@ -38,7 +38,7 @@ from repro.core.poisson import JoinSample
 
 from . import executors
 from .capacity import CapacityPolicy, DEFAULT_POLICY
-from .plan import draw_call, redraw_with_doubling
+from .plan import draw_call, redraw_with_doubling, resolve_narrow
 from .spec import DrawSpec
 
 __all__ = ["ShardPlan", "ShardedPlan", "plan_shards", "BATCH_AXES"]
@@ -135,8 +135,8 @@ class ShardedPlan:
         self.axes = tuple(axes)
         self.sharding = NamedSharding(self.mesh, P(self.axes))
         self.policy = policy
-        self._samplers: Dict[Tuple[int, int], callable] = {}
-        self._batched_samplers: Dict[Tuple[int, int], callable] = {}
+        self._samplers: Dict[Tuple[int, int, bool], callable] = {}
+        self._batched_samplers: Dict[Tuple[int, int, bool], callable] = {}
         self._flattener = None
         self._bind_stacked(stacked)
 
@@ -146,28 +146,17 @@ class ShardedPlan:
         # index left on one device would be re-sharded by every call.
         stacked = place_stacked(stacked, self.sharding)
         self.stacked = stacked
-        # Executor rep + int32-narrowing selection (probe.select_rep — the
-        # same policy as the single-device plan, over the stacked arena
-        # with its leading shard dim; DESIGN.md §4). Both verdicts are
-        # baked into the shard_map partials, so a rebind that flips either
-        # invalidates the executor caches (a retrace, not a rebuild — same
-        # economics as a capacity change). The spec's ``narrow`` override
-        # wins over the auto verdict, exactly like the single-device plan.
-        rep, narrow = probe.select_rep(stacked.shred, self._base_rep)
-        if self.spec.narrow is not None:
-            if (self.spec.narrow and stacked.shred.packed is None
-                    and stacked.shred.paged is None):
-                raise ValueError(
-                    "DrawSpec(narrow=True) requires a packed int32 index; "
-                    "this stacked shred has none")
-            narrow = self.spec.narrow
-        if (getattr(self, "rep", None), getattr(self, "_narrow", None)) \
-                != (rep, narrow):
+        # Executor rep (probe.select_rep — the same policy as the
+        # single-device plan, over the stacked arena with its leading
+        # shard dim; DESIGN.md §4). Baked into the shard_map partials, so
+        # a rebind that flips it invalidates the executor caches (a
+        # retrace, not a rebuild — same economics as a capacity change).
+        rep = probe.select_rep(stacked.shred, self._base_rep)
+        if getattr(self, "rep", None) != rep:
             self._samplers.clear()
             self._batched_samplers.clear()
             self._flattener = None
         self.rep = rep
-        self._narrow = narrow
         self.num_shards = stacked.num_shards
         self.join_sizes = stacked.join_sizes
         # Global flat offset of each shard's position space: shard flattens
@@ -198,6 +187,10 @@ class ShardedPlan:
             self.cap = None
             self.acap = 0
             self.massE = None
+        # EXPRACE's width, as in the single-device plan: the global join
+        # size bounds every shard's. Samplers are cached per width.
+        self._narrow = resolve_narrow(self.spec.narrow, stacked.join_size,
+                                      int(stacked.w.shape[-1]), self.acap)
         flat_cap = max(getattr(self, "flat_cap", 0),
                        self.policy.flatten_capacity(
                            max(self.join_sizes, default=0)))
@@ -267,37 +260,43 @@ class ShardedPlan:
         cols = probe.get(shred, pos, rep=rep)
         return jax.tree.map(lambda x: x[None], cols), n[None]
 
-    def _sampler(self, cap: int, acap: int):
-        fn = self._samplers.get((cap, acap))
+    def _narrow_call(self, cap: int, acap: int) -> bool:
+        """Whether a call at these capacities runs EXPRACE in int32: the
+        bound verdict, at capacities the certificate still covers."""
+        return self._narrow and sampling.exprace_fits_int32(
+            self.join_size, int(self.stacked.w.shape[-1]), acap, cap)
+
+    def _sampler(self, cap: int, acap: int, narrow: bool):
+        fn = self._samplers.get((cap, acap, narrow))
         if fn is None:
             spec = P(self.axes)
             fn = jax.jit(jax.shard_map(
                 partial(self._local_sample, cap=cap, acap=acap, rep=self.rep,
                         method=self.method, project=self.project,
-                        axes=self.axes, narrow=self._narrow),
+                        axes=self.axes, narrow=narrow),
                 mesh=self.mesh,
                 in_specs=(spec, spec, spec, spec, spec, P()),
                 out_specs=(spec, P()),
                 check_vma=False,
             ))
-            self._samplers[(cap, acap)] = fn
+            self._samplers[(cap, acap, narrow)] = fn
         return fn
 
-    def _batched_sampler(self, cap: int, acap: int):
-        fn = self._batched_samplers.get((cap, acap))
+    def _batched_sampler(self, cap: int, acap: int, narrow: bool):
+        fn = self._batched_samplers.get((cap, acap, narrow))
         if fn is None:
             spec = P(self.axes)
             fn = jax.jit(jax.shard_map(
                 partial(self._local_sample_batch, cap=cap, acap=acap,
                         rep=self.rep, method=self.method,
                         project=self.project, axes=self.axes,
-                        narrow=self._narrow),
+                        narrow=narrow),
                 mesh=self.mesh,
                 in_specs=(spec, spec, spec, spec, spec, P()),
                 out_specs=(spec, P()),
                 check_vma=False,
             ))
-            self._batched_samplers[(cap, acap)] = fn
+            self._batched_samplers[(cap, acap, narrow)] = fn
         return fn
 
     def _flatten_fn(self):
@@ -322,8 +321,9 @@ class ShardedPlan:
             raise ValueError("plan has no prob_var; use full_join")
         st = self.stacked
         cap, acap = cap or self.cap, acap or self.acap
-        with draw_call("sharded", cap, acap, 1, self.num_shards):
-            return self._sampler(cap, acap)(
+        narrow = self._narrow_call(cap, acap)
+        with draw_call("sharded", cap, acap, 1, self.num_shards, narrow):
+            return self._sampler(cap, acap, narrow)(
                 st.shred, st.w, st.p, st.prefE, self.massE, key)
 
     def _call_overrides(self, spec: Optional[DrawSpec], cap, acap):
@@ -402,9 +402,10 @@ class ShardedPlan:
         kpad, _ = executors.pad_batch_keys(keys)
         st = self.stacked
         cap, acap = cap or self.cap, acap or self.acap
+        narrow = self._narrow_call(cap, acap)
         with draw_call("sharded", cap, acap, batch,
-                       int(kpad.shape[0]) * self.num_shards):
-            smp, _totals = self._batched_sampler(cap, acap)(
+                       int(kpad.shape[0]) * self.num_shards, narrow):
+            smp, _totals = self._batched_sampler(cap, acap, narrow)(
                 st.shred, st.w, st.p, st.prefE, self.massE, kpad)
         # Host gather per lane (padding lanes never gathered), then stack.
         columns = {v: np.asarray(a) for v, a in smp.columns.items()}

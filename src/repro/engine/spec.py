@@ -48,9 +48,10 @@ class DrawSpec:
     cap      sample capacity override (static shape; one cached trace per
              value inside a plan — never a new plan).
     acap     EXPRACE arrival-scratch capacity override.
-    narrow   int32-narrowed sampler searches: None = auto (on iff the index
-             packed an int32 arena and the backend prefers Pallas), True =
-             force on (requires a packed index), False = force off.
+    narrow   EXPRACE's integer pipeline in int32: None = auto (on iff the
+             join certifies it, ``sampling.exprace_fits_int32``: n + R + 1
+             and the arrival capacity below 2^31), True = force on
+             (raises where the certificate fails), False = force off.
     kernels  draw-kernel route (DESIGN.md §14/§15): ``auto`` = the
              one-launch fused draw iff capable and the active
              ``KernelPolicy`` prefers it, degrading to the *paged* route

@@ -7,7 +7,8 @@
   context, the span is also kept there with its parent, on
   ``time.perf_counter_ns``.
 * ``count(name, n)`` adds to the open record's counters: each draw
-  executor call counts ``draws`` and ``draw_arrival_lanes``.
+  executor call counts ``draws``, ``draws_int32`` where EXPRACE runs in
+  int32, and ``draw_arrival_lanes``.
 * Device phases inside jitted code are named with ``jax.named_scope``
   (the names in ``SCOPES``). A scope changes only the op-name metadata of
   the compiled program, never what it computes; a ``TraceAnnotation``
@@ -40,7 +41,8 @@ SPANS = (
     "engine_sample",        # QueryEngine.poisson_sample
     "engine_sample_batch",  # QueryEngine.sample_batch
     "engine_full_join",     # QueryEngine.full_join
-    "plan_draw",            # one draw executor call: route, cap, acap, batch
+    "plan_draw",            # one draw executor call: route, cap, acap,
+                            #   batch, narrow
     "batcher_flush",        # MicroBatcher.flush
     "batcher_keys",         #   stacking the requests' PRNG keys
     "batcher_dispatch",     #   the engine call

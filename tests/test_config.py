@@ -70,7 +70,7 @@ class TestTpuCapability:
         dp = sampling.fused_draw_params(shred.root.weight, p,
                                         shred.root_prefE)
         pol = config.KernelPolicy(interpret=False)
-        assert probe.select_rep(shred, "usr", pol) == ("usr", True)
+        assert probe.select_rep(shred, "usr", pol) == "usr"
         assert probe.select_draw(shred, dp, method="exprace",
                                  policy=pol) == "pernode"
 

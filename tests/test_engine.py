@@ -236,3 +236,95 @@ def test_tpu_search_route_is_bit_identical(db, query, monkeypatch):
     got = run()
     for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# -- EXPRACE's int32 certificate (DESIGN.md §4) ------------------------------
+
+def _leaves_equal(a, b):
+    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+def test_unpacked_shred_binds_narrow(db, query):
+    """The join size certifies int32, not the packed arena: a plan over a
+    shred with neither arena nor pages still narrows, and draws what the
+    packed plan draws."""
+    import dataclasses
+
+    from repro.engine.plan import CompiledPlan
+
+    plan = QueryEngine(db).compile(query)
+    bare = dataclasses.replace(plan.shred, packed=None, paged=None)
+    unpacked = CompiledPlan(query, plan.spec, bare)
+    assert plan.narrow and unpacked.narrow
+    key = jax.random.key(2)
+    _leaves_equal(unpacked.sample(key), plan.sample(key))
+
+
+def test_narrow_off_draws_the_same_sample(db, query):
+    engine = QueryEngine(db)
+    narrow, wide = engine.compile(query), engine.compile(query, narrow=False)
+    assert narrow.narrow and not wide.narrow
+    for s in range(3):
+        key = jax.random.key(s)
+        _leaves_equal(narrow.sample(key), wide.sample(key))
+    keys = jax.random.split(jax.random.key(9), 3)
+    _leaves_equal(narrow.sample_batch(keys), wide.sample_batch(keys))
+
+
+@pytest.fixture
+def past_int32(monkeypatch):
+    """The certificate as it reads a join 2^31 tuples larger than the one
+    built, so a small join fails it."""
+    from repro.core import sampling
+
+    fits = sampling.exprace_fits_int32
+    monkeypatch.setattr(sampling, "exprace_fits_int32",
+                        lambda n, R, acap, cap=0: fits(n + (1 << 31), R,
+                                                       acap, cap))
+
+
+def test_forced_narrow_raises_when_the_certificate_fails(db, query,
+                                                         past_int32):
+    with pytest.raises(ValueError, match="narrow=True"):
+        QueryEngine(db).compile(query, narrow=True)
+
+
+def test_joins_past_int32_trace_the_int64_program(db, query, past_int32,
+                                                  monkeypatch):
+    from repro import obs
+    from repro.core import sampling
+
+    traced = []
+    positions = sampling.exprace_positions
+
+    def spy(*args, narrow=False, **kw):
+        traced.append(narrow)
+        return positions(*args, narrow=narrow, **kw)
+
+    monkeypatch.setattr(sampling, "exprace_positions", spy)
+    plan = QueryEngine(db).compile(query)
+    assert not plan.narrow
+    with obs.record() as rec:
+        plan.sample(jax.random.key(4))
+    assert traced == [False]
+    assert rec.counters["draws"] == 1 and "draws_int32" not in rec.counters
+
+
+@pytest.mark.parametrize("override,n,fits", [
+    (None, (1 << 31) - 2 - 90, True),    # n + R + 1 = 2^31 - 1, R = 90
+    (None, (1 << 31) - 1 - 90, False),   # n + R + 1 = 2^31
+    (False, 1000, False),
+    (True, 1000, True),
+])
+def test_resolve_narrow(override, n, fits):
+    from repro.engine.plan import resolve_narrow
+
+    assert resolve_narrow(override, n, 90, 4096) is fits
+
+
+def test_resolve_narrow_refuses_a_forced_width_past_int32():
+    from repro.engine.plan import resolve_narrow
+
+    with pytest.raises(ValueError, match="2\\^31"):
+        resolve_narrow(True, (1 << 31) - 1 - 90, 90, 4096)

@@ -185,8 +185,9 @@ def test_engine_calls_open_their_spans_and_count_draws(job_plan):
             if s.parent is not None] == [("engine_sample", "plan_draw"),
                                          ("engine_sample_batch", "plan_draw")]
     acap = plan.arrival_capacity()
-    # three keys run as a bucket of four lanes
-    assert rec.counters == {"draws": 4, "draw_arrival_lanes": acap * 5}
+    # three keys run as a bucket of four lanes; the join certifies int32
+    assert rec.counters == {"draws": 4, "draws_int32": 4,
+                            "draw_arrival_lanes": acap * 5}
 
 
 def test_redraws_count_each_executor_call_at_its_capacity(job_plan):
@@ -198,7 +199,7 @@ def test_redraws_count_each_executor_call_at_its_capacity(job_plan):
         smp = engine.poisson_sample(q, jax.random.key(3), cap=2, auto=True)
     calls = [s for s in rec.spans if s.name == "plan_draw"]
     assert not bool(smp.overflow) and len(calls) >= 2
-    assert rec.counters == {"draws": len(calls),
+    assert rec.counters == {"draws": len(calls), "draws_int32": len(calls),
                             "draw_arrival_lanes": acap * (2 ** len(calls)
                                                           - 1)}
 
@@ -218,7 +219,43 @@ def test_a_sharded_draw_counts_the_lanes_of_every_shard(job_plan):
     assert [s.name for s in rec.spans] == [
         "engine_sample", "plan_draw", "engine_sample_batch", "plan_draw"]
     assert rec.counters == {
-        "draws": 4, "draw_arrival_lanes": plan.acap * plan.num_shards * 5}
+        "draws": 4, "draws_int32": 4,
+        "draw_arrival_lanes": plan.acap * plan.num_shards * 5}
+
+
+def test_draws_int32_counts_only_narrowed_draws(job_plan):
+    """With int32 forced off, no call counts ``draws_int32``; the plan
+    that certifies it counts every draw, a batch's as ``draws`` does."""
+    engine, q, plan = job_plan
+    wide = engine.compile(q, narrow=False)
+    assert plan.narrow and not wide.narrow
+    with obs.record() as off:
+        wide.sample(jax.random.key(6))
+        wide.sample_batch(jax.random.split(jax.random.key(7), 3))
+    assert "draws_int32" not in off.counters and off.counters["draws"] == 4
+    with obs.record() as on:
+        plan.sample_batch(jax.random.split(jax.random.key(7), 5))
+    assert on.counters["draws_int32"] == on.counters["draws"] == 5
+
+
+def test_plan_draw_span_carries_narrow(job_plan, tmp_path):
+    from jax.profiler import ProfileData
+
+    engine, q, plan = job_plan
+    wide = engine.compile(q, narrow=False)
+    key = jax.random.key(8)
+    for p in (plan, wide):  # warm: compiles stay out of the trace
+        p.sample(key).positions.block_until_ready()
+    jax.profiler.start_trace(str(tmp_path))
+    for p in (plan, wide):
+        p.sample(key).positions.block_until_ready()
+    jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    seen = [dict(e.stats)["narrow"]
+            for plane in ProfileData.from_file(path).planes
+            for line in plane.lines for e in line.events
+            if e.name == "plan_draw"]
+    assert [int(v) for v in seen] == [1, 0]  # a bool arg reads as 1 or 0
 
 
 def test_index_build_opens_its_passes(job_plan):

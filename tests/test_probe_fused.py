@@ -368,19 +368,19 @@ class TestPagedRung:
             pol = dataclasses.replace(base, vmem_limit=limit)
             with config.override(pol):
                 sh = build_shred(db, q, rep="usr")
-                rep, narrow = probe.select_rep(sh, "usr")
+                rep = probe.select_rep(sh, "usr")
                 route = probe.select_draw(sh, self._dparams(sh),
                                           method="exprace")
-            ladder.append((limit, rep, narrow, route))
+            ladder.append((limit, rep, route))
         assert ladder == [
-            (size, "usr_fused", True, "fused"),
-            (size - 1, "usr_paged", True, "paged"),
-            (max_page, "usr_paged", True, "paged"),
-            (max_page - 1, "usr", False, "pernode"),
+            (size, "usr_fused", "fused"),
+            (size - 1, "usr_paged", "paged"),
+            (max_page, "usr_paged", "paged"),
+            (max_page - 1, "usr", "pernode"),
         ]
         # Call-time shrink (no rebuild): an already-packed index pages too.
         with config.override(dataclasses.replace(base, vmem_limit=size - 1)):
-            assert probe.select_rep(shred, "usr")[0] == "usr_paged"
+            assert probe.select_rep(shred, "usr") == "usr_paged"
             assert probe.select_draw(shred, dp, method="exprace") == "paged"
 
     def test_pack_index_mutual_exclusivity(self):

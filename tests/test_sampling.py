@@ -175,6 +175,107 @@ class TestExprace:
         assert abs(z) < 4.5, f"EXPRACE vs host oracle: z={z:.2f}"
 
 
+# (weights, probabilities, cap, acap) of the int32/int64 equality cases;
+# ``None`` weights draw 40 random roots.
+_WIDTH_CASES = {
+    "p_zero": ([6, 3, 9], [0.0, 0.0, 0.0], 32, 64),
+    "p_one": ([6, 3, 9], [1.0, 1.0, 1.0], 32, 64),
+    "p_below_half": (None, "low", 256, 512),
+    "p_above_half": (None, "high", 768, 512),
+    "zero_weight_roots": ([0, 6, 0, 4, 0], [1.0, 0.5, 1.0, 0.7, 0.2], 16, 32),
+    "single_root": ([25], [0.4], 32, 64),
+    "arrivals_overflow": (None, "low", 256, 8),
+    "sample_overflow": (None, "high", 8, 512),
+    "acap_below_cap": (None, "mixed", 512, 320),
+}
+
+
+def _width_inputs(case):
+    wv, pv, cap, acap = _WIDTH_CASES[case]
+    if wv is None:
+        rng = np.random.default_rng(len(case))
+        wv = rng.integers(0, 30, 40)
+        lo, hi = {"low": (0.0, 0.5), "high": (0.5, 1.0),
+                  "mixed": (0.0, 1.0)}[pv]
+        pv = rng.uniform(lo, hi, 40)
+    w = jnp.asarray(wv, jnp.int64)
+    p = jnp.asarray(pv, jnp.float64)
+    prefE = jnp.concatenate([jnp.zeros(1, jnp.int64), jnp.cumsum(w)])
+    return (w, p, prefE, sampling.exprace_mass(w, p)), cap, acap
+
+
+def _draw_widths(args, cap, acap, key):
+    return [jax.jit(partial(sampling.exprace_positions, cap=cap,
+                            arrival_cap=acap, narrow=narrow))(key, *args)
+            for narrow in (True, False)]
+
+
+def _assert_same_sample(a, b):
+    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+        assert x.dtype == y.dtype
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+@pytest.mark.parametrize("case", sorted(_WIDTH_CASES))
+def test_exprace_int32_matches_int64(case):
+    """EXPRACE's int32 program gives the int64 one's positions, count and
+    overflow flag, bit for bit, for the same key."""
+    args, cap, acap = _width_inputs(case)
+    flags = []
+    for s in range(6):
+        narrow, wide = _draw_widths(args, cap, acap, jax.random.key(s))
+        _assert_same_sample(narrow, wide)
+        flags.append(bool(wide.overflow))
+    # the overflow cases reach their overflow
+    assert any(flags) == case.endswith("_overflow")
+
+
+def test_exprace_int32_matches_int64_vmapped():
+    """A vmapped batch of keys in int32 draws, lane for lane, the int64
+    program's standalone sample."""
+    args, cap, acap = _width_inputs("acap_below_cap")
+    keys = jax.random.split(jax.random.key(3), 5)
+    batch = jax.jit(jax.vmap(partial(
+        sampling.exprace_positions, cap=cap, arrival_cap=acap, narrow=True),
+        in_axes=(0, None, None, None, None)))(keys, *args)
+    for b in range(keys.shape[0]):
+        _, wide = _draw_widths(args, cap, acap, keys[b])
+        _assert_same_sample(jax.tree.map(lambda x: x[b], batch), wide)
+
+
+@pytest.mark.parametrize("narrow,dtype", [(True, jnp.int32),
+                                          (False, jnp.int64)])
+def test_exprace_traces_its_width(narrow, dtype):
+    """The dedupe sort runs at the traced width: int32 when certified,
+    today's int64 program otherwise."""
+    args, cap, acap = _width_inputs("p_below_half")
+    jaxpr = jax.make_jaxpr(partial(sampling.exprace_positions, cap=cap,
+                                   arrival_cap=acap, narrow=narrow))(
+        jax.random.key(0), *args)
+
+    def sorted_dtypes(jx):
+        for e in jx.eqns:
+            if e.primitive.name == "sort":
+                yield from (v.aval.dtype for v in e.invars)
+            for sub in e.params.values():
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                    yield from sorted_dtypes(sub.jaxpr)
+
+    assert list(sorted_dtypes(jaxpr.jaxpr)) == [dtype]
+
+
+@pytest.mark.parametrize("n,R,acap,cap,fits", [
+    ((1 << 31) - 2 - 5, 5, 1, 1, True),      # n + R + 1 = 2^31 - 1
+    ((1 << 31) - 1 - 5, 5, 1, 1, False),     # n + R + 1 = 2^31
+    (37_409_101, 2_528_312, 7_113_216, 6_247_552, True),  # JOB star
+    (100, 10, (1 << 31) - 1, 1, True),
+    (100, 10, 1 << 31, 1, False),
+    (100, 10, 1, 1 << 31, False),
+])
+def test_int32_certificate_bounds(n, R, acap, cap, fits):
+    assert sampling.exprace_fits_int32(n, R, acap, cap) is fits
+
+
 def test_host_oracle_methods_agree():
     rng = np.random.default_rng(1)
     w, p = [30, 40], [0.2, 0.45]

@@ -134,12 +134,14 @@ def test_declared_unlowerable_kernels_still_fail(one_chip, kernel):
     assert message in str(err.value)
 
 
-def test_engine_draw_step_compiles(one_chip, monkeypatch):
-    """The engine's jitted single-draw executor on the route a TPU binds
-    (per-node USR GET, XLA EXPRACE, searches as ``ops.kary_searchsorted``
-    — what ``ops.searchsorted`` picks on a TPU backend, which this process
-    is not), with the plan's own capacities.
+@pytest.fixture(scope="module")
+def draw_step(one_chip):
+    """The engine's jitted single-draw executor compiled for the described
+    chip, at EXPRACE's int32 or int64 width (memoized per width).
 
+    It runs the route a TPU binds (per-node USR GET, XLA EXPRACE, searches
+    as ``ops.kary_searchsorted`` — what ``ops.searchsorted`` picks on a TPU
+    backend, which this process is not), with the plan's own capacities.
     The index shapes come from a real plan, so this runs at scale=2000,
     not at the chip_smoke width: the program is the same at any size (no
     op depends on the index width), and the full-width compile takes the
@@ -151,13 +153,31 @@ def test_engine_draw_step_compiles(one_chip, monkeypatch):
 
     db, q = job_like(seed=0, scale=2000)
     plan = QueryEngine(db).compile(q)
-    monkeypatch.setattr(ops, "searchsorted", ops.kary_searchsorted)
     sds = lambda x: _spec(x.shape, x.dtype, one_chip)  # noqa: E731
     args = jax.tree.map(sds, (plan.shred, plan.w, plan.p, plan.prefE))
     key = _spec((), jax.random.key(0).dtype, one_chip)
-    c = plan._jit.lower(
-        *args, key, cap=plan.default_capacity(), rep="usr", n=0,
-        acap=plan.arrival_capacity(), narrow=False, route="pernode",
-        dparams=None, massE=sds(np.asarray(plan._massE))).compile()
-    assert c.memory_analysis().temp_size_in_bytes < 16 << 30
+    compiled = {}
+
+    def compile_at(narrow):
+        if narrow not in compiled:
+            with pytest.MonkeyPatch.context() as mp:
+                mp.setattr(ops, "searchsorted", ops.kary_searchsorted)
+                compiled[narrow] = plan._jit.lower(
+                    *args, key, cap=plan.default_capacity(), rep="usr", n=0,
+                    acap=plan.arrival_capacity(), narrow=narrow,
+                    route="pernode", dparams=None,
+                    massE=sds(np.asarray(plan._massE))).compile()
+        return compiled[narrow]
+
+    return compile_at
+
+
+@pytest.mark.parametrize("narrow", [False, True])
+def test_engine_draw_step_compiles(draw_step, narrow):
+    """The draw step compiles at both widths; int32 needs less scratch."""
+    c = draw_step(narrow)
+    temp = c.memory_analysis().temp_size_in_bytes
+    assert temp < 16 << 30
     assert "slice_sizes={1,128}" in c.as_text()  # the searches' row gathers
+    if narrow:
+        assert temp < draw_step(False).memory_analysis().temp_size_in_bytes
