@@ -2,6 +2,8 @@
 
 Public API:
     Database, Relation, Atom, JoinQuery       data model / queries
+    Float64Bits                               a float64 column, held as its
+                                              int64 bit patterns (DESIGN.md §9)
     DeltaBatch, Database.apply                versioned snapshots (DESIGN.md §11)
     build_shred, Shred, get                   random-access index (CSR/USR)
     reshred_incremental                       merge a delta into an index
@@ -26,7 +28,7 @@ import jax as _jax
 
 _jax.config.update("jax_enable_x64", True)
 
-from .relations import Relation, pack_keys, dense_keys  # noqa: E402
+from .relations import Relation, Float64Bits, pack_keys, dense_keys  # noqa: E402
 from .database import Database  # noqa: E402
 from .delta import DeltaBatch, RelationDelta  # noqa: E402
 from .jointree import Atom, JoinQuery, gyo_join_tree, is_acyclic, reroot_for  # noqa: E402
@@ -39,7 +41,7 @@ from . import sampling, estimate, yannakakis  # noqa: E402
 from .poisson import PoissonSampler, JoinSample  # noqa: E402
 
 __all__ = [
-    "Relation", "Database", "DeltaBatch", "RelationDelta", "Atom",
+    "Relation", "Float64Bits", "Database", "DeltaBatch", "RelationDelta", "Atom",
     "JoinQuery", "gyo_join_tree", "is_acyclic",
     "reroot_for", "Shred", "ShredNode", "build_shred", "build_plan",
     "reshred_incremental", "PackedShred", "PagedArena", "pack_arena",

@@ -11,10 +11,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, Mapping, Sequence, Tuple
 
-import jax.numpy as jnp
-import numpy as np
-
-from .relations import Relation
+from .relations import Relation, place_column
 from .jointree import Atom
 
 __all__ = ["Database"]
@@ -39,10 +36,12 @@ class Database:
 
     @staticmethod
     def from_columns(tables: Mapping[str, Mapping[str, Sequence]]) -> "Database":
+        """Host columns placed on the device; float64 ones as their bit
+        patterns (``relations.Float64Bits``)."""
         rels, schemas = {}, {}
         for name, cols in tables.items():
             schemas[name] = tuple(cols.keys())
-            rels[name] = Relation({c: jnp.asarray(np.asarray(v)) for c, v in cols.items()})
+            rels[name] = Relation({c: place_column(v) for c, v in cols.items()})
         return Database(rels, schemas)
 
     def size(self) -> int:

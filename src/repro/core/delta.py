@@ -25,6 +25,8 @@ from typing import Dict, Mapping, Optional, Sequence, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from .relations import concat_columns, place_column
+
 __all__ = ["DeltaBatch", "RelationDelta"]
 
 
@@ -184,6 +186,7 @@ def apply_relation_delta(columns: Dict[str, jnp.ndarray],
     for c, v in columns.items():
         nv = v[keep] if keep is not None else v
         if d.inserts:
-            nv = jnp.concatenate([nv, jnp.asarray(d.inserts[c]).astype(nv.dtype)])
+            ins = np.asarray(d.inserts[c]).astype(nv.dtype)
+            nv = concat_columns([nv, place_column(ins)])
         out[c] = nv
     return out

@@ -40,7 +40,7 @@ from jax.sharding import Mesh, NamedSharding
 
 from .database import Database
 from .jointree import JoinQuery, JoinTreeNode
-from .relations import Relation
+from .relations import Relation, numeric_column, take_column
 from .shred import (Shred, _dense_gids_np, _np_i64, build_plan, build_shred,
                     pack_index, root_prefix)
 
@@ -153,7 +153,7 @@ def partition_root(
             idx = np.concatenate([idx, np.full(per - (hi - lo), max(n - 1, 0))])
         cols = {}
         for c, v in root_rel.columns.items():
-            col = jnp.take(v, jnp.asarray(idx), axis=0)
+            col = take_column(v, jnp.asarray(idx))
             if c == prob_col and hi - lo < per:
                 col = col.at[hi - lo:].set(0)
             cols[c] = col
@@ -223,7 +223,9 @@ def _stack_shards(built, part: RootPartition, query: JoinQuery,
     shred = jax.tree.map(lambda *xs: jnp.stack(xs), *built)
     w = jnp.stack([b.root.weight for b in built])
     pvar = query.prob_var
-    p = (jnp.stack([b.root.data.column(pvar) for b in built])
+    # The samplers read p as a number (a float64 column is decoded from
+    # its bit patterns on the host), as the single-device plan binds it.
+    p = (jnp.stack([numeric_column(b.root.data.column(pvar)) for b in built])
          if pvar is not None else None)
     prefE = jnp.stack([b.root_prefE for b in built])
     return StackedShred(
@@ -283,7 +285,8 @@ def _relations_equal(a, b) -> bool:
         x, y = a.columns[c], b.columns[c]
         if x is not y and (
                 x.dtype != y.dtype or x.shape != y.shape
-                or not bool(jnp.array_equal(x, y))):
+                or type(x) is not type(y)
+                or not bool(jnp.array_equal(*jax.tree.leaves((x, y))))):
             return False
     return True
 

@@ -39,7 +39,11 @@ class JoinSample:
     with a leading batch axis on every leaf: columns/positions ``(B, cap)``,
     count/overflow ``(B,)``. ``capacity``/``valid`` are batch-aware (the
     capacity is always the *last* axis; ``valid()`` broadcasts the per-draw
-    counts), so masking code works unchanged on either layout."""
+    counts), so masking code works unchanged on either layout.
+
+    A float64 attribute's column is a ``relations.Float64Bits``: its int64
+    bit patterns on the device; ``np.asarray(column)`` gives the stored
+    float64 values, exactly (DESIGN.md §9)."""
 
     columns: Dict[str, jnp.ndarray]
     positions: jnp.ndarray  # (cap,) flat offsets into the virtual join

@@ -38,6 +38,7 @@ from repro.kernels import ops
 from repro.kernels.fused_draw import fused_draw, fused_draw_ref, fused_sample
 from repro.kernels.tree_probe import tree_probe, tree_probe_paged
 
+from .relations import Float64Bits, take_column
 from .sampling import PositionSample
 from .shred import PagedArena, Shred, ShredNode
 
@@ -556,13 +557,20 @@ def get_rows(shred: Shred, pos: jnp.ndarray, rep: str = None) -> Dict[str, jnp.n
 def gather_columns(shred: Shred, node_rows: Dict[str, jnp.ndarray]) -> Dict[str, jnp.ndarray]:
     """Per-node row indices -> owned output columns (the gather half of
     GET).  Shared by the positional routes (``get``) and the fused draw,
-    whose kernel already resolved the rows in-launch."""
+    whose kernel already resolved the rows in-launch.  A float64 column
+    is gathered as its int64 bit patterns (``Float64Bits``), under the
+    nested scope ``usr_gather_bits``."""
     out: Dict[str, jnp.ndarray] = {}
     with jax.named_scope("usr_gather"):
         for node in shred.root.nodes():
             rows = node_rows[node.name]
             for v in node.owned:
-                out[v] = jnp.take(node.data.column(v), rows, axis=0)
+                col = node.data.column(v)
+                if isinstance(col, Float64Bits):
+                    with jax.named_scope("usr_gather_bits"):
+                        out[v] = take_column(col, rows)
+                else:
+                    out[v] = jnp.take(col, rows, axis=0)
     return out
 
 

@@ -39,7 +39,7 @@ from repro.kernels import ops as _kops
 
 from .database import Database
 from .jointree import Atom, JoinQuery, JoinTreeNode, gyo_join_tree, reroot_for
-from .relations import Relation
+from .relations import Relation, place_column
 
 __all__ = ["ShredNode", "Shred", "build_shred", "build_plan",
            "reshred_incremental", "PackedShred", "PagedArena", "ArenaLayout",
@@ -656,7 +656,7 @@ def _apply_instance_delta(data: Relation, atom: Atom,
         if rd.inserts:
             ins = np.asarray(rd.inserts[colmap[v]]).astype(nv.dtype)
             nv = np.concatenate([nv, ins])
-        cols[v] = jnp.asarray(nv)
+        cols[v] = place_column(nv)
     return Relation(cols)
 
 

@@ -21,7 +21,7 @@ import numpy as np
 from . import probe
 from .database import Database
 from .jointree import JoinQuery, JoinTreeNode, gyo_join_tree
-from .relations import Relation, dense_keys
+from .relations import Relation, dense_keys, numeric_column, take_column
 from .shred import Shred, build_shred
 
 __all__ = ["flatten", "full_join", "materialize_and_scan", "binary_join"]
@@ -78,7 +78,7 @@ def materialize_and_scan(
         pflat = jnp.full((n,), uniform_p, jnp.float64)
     else:
         assert query.prob_var is not None
-        pflat = cols[query.prob_var].astype(jnp.float64)
+        pflat = numeric_column(cols[query.prob_var]).astype(jnp.float64)
     keep = jax.random.uniform(key, (max(n, 1),), jnp.float64)[:n] < pflat
     return cols, keep
 
@@ -113,10 +113,11 @@ def _pairwise_join(left: Relation, right: Relation) -> Relation:
     offs = np.arange(total) - base
     rpos = np.asarray(s)[lrow] + offs
     rrow = np.asarray(order)[rpos] if total else np.zeros((0,), np.int64)
-    out = {v: left.column(v)[jnp.asarray(lrow)] for v in left.attrs}
+    out = {v: take_column(left.column(v), jnp.asarray(lrow))
+           for v in left.attrs}
     for v in right.attrs:
         if v not in out:
-            out[v] = right.column(v)[jnp.asarray(rrow)]
+            out[v] = take_column(right.column(v), jnp.asarray(rrow))
     return Relation(out)
 
 

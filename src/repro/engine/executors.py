@@ -27,6 +27,7 @@ import jax.numpy as jnp
 
 from repro.core import probe, sampling
 from repro.core.poisson import JoinSample
+from repro.core.relations import zeros_like_column
 from repro.core.shred import Shred
 
 __all__ = [
@@ -133,7 +134,7 @@ def pad_batch_keys(keys) -> Tuple[jnp.ndarray, int]:
 
 def empty_sample(shred: Shred, cap: int) -> JoinSample:
     """An all-padding sample (used when |Q(db)| == 0: nothing to probe)."""
-    cols = {v: jnp.zeros((cap,), node.data.column(v).dtype)
+    cols = {v: zeros_like_column(node.data.column(v), (cap,))
             for node in shred.root.nodes() for v in node.owned}
     return JoinSample(cols, jnp.zeros((cap,), jnp.int64),
                       jnp.zeros((), jnp.int64), jnp.zeros((), jnp.bool_))

@@ -21,6 +21,7 @@ from repro import obs
 from repro.core import estimate, probe, sampling
 from repro.core.jointree import JoinQuery
 from repro.core.poisson import JoinSample
+from repro.core.relations import Float64Bits
 from repro.core.shred import Shred
 from repro.core.yannakakis import flatten
 
@@ -29,21 +30,23 @@ from .capacity import CapacityPolicy, DEFAULT_POLICY
 from .spec import DrawSpec
 
 __all__ = ["CompiledPlan", "redraw_with_doubling", "draw_call",
-           "resolve_narrow"]
+           "resolve_narrow", "bit_columns"]
 
 
 def draw_call(route: str, cap: int, acap: int, draws: int, lanes: int,
-              narrow: bool):
+              narrow: bool, bit_columns: int):
     """The span of one draw executor call, with the route, capacities and
     sampler width it runs at, and the call's draw counters (DESIGN.md
     §16): ``draws`` draws, ``draws_int32`` of them when EXPRACE runs in
-    int32 (``narrow``), and ``acap`` arrival lanes for each of the
-    ``lanes`` lanes the executor computes (a batch's padding lanes and
-    every shard included)."""
+    int32 (``narrow``), ``acap`` arrival lanes for each of the ``lanes``
+    lanes the executor computes (a batch's padding lanes and every shard
+    included), and ``draw_bit_columns``, the float64 columns carried as
+    bit patterns (``bit_columns`` a draw) times the draws."""
     obs.count("draws", draws)
     if narrow:
         obs.count("draws_int32", draws)
     obs.count("draw_arrival_lanes", acap * lanes)
+    obs.count("draw_bit_columns", bit_columns * draws)
     return obs.span("plan_draw", route=route, cap=cap, acap=acap,
                     batch=draws, narrow=narrow)
 
@@ -60,6 +63,14 @@ def resolve_narrow(override: Optional[bool], n: int, R: int,
             f"DrawSpec(narrow=True) needs a join that fits int32: "
             f"n + R + 1 = {n + R + 1} and acap = {acap} must be below 2^31")
     return fits if override is None else override
+
+
+def bit_columns(shred, project: Optional[Tuple[str, ...]]) -> int:
+    """The output columns of a draw over ``shred`` that are float64, held
+    and gathered as bit patterns (``relations.Float64Bits``)."""
+    return sum(isinstance(node.data.column(v), Float64Bits)
+               for node in shred.root.nodes() for v in node.owned
+               if project is None or v in project)
 
 
 def redraw_with_doubling(draw, cap: int, acap: int, max_doublings: int):
@@ -136,15 +147,21 @@ class CompiledPlan:
         # Explicit per-call rep overrides still win in sample()/full_join().
         self.rep_default = probe.select_rep(
             shred, "usr" if self.rep == "both" else self.rep)
+        self._bit_columns = bit_columns(shred, self.project)
         if self.query.prob_var is not None:
             if self.query.prob_var not in root.variables:
                 raise AssertionError("build_plan must reroot prob_var to the root")
-            self.p = root.data.column(self.query.prob_var)
             # Bind-time tables (capacities, the EXPRACE mass prefix, the
             # fused-draw operands) are float64 scans and reductions over
             # the plan-bound vectors: computed once per bind in host numpy,
             # which the TPU compiler would otherwise spend minutes on.
-            w, p = np.asarray(self.w), np.asarray(self.p)
+            w = np.asarray(self.w)
+            p_col = root.data.column(self.query.prob_var)
+            p = np.asarray(p_col)
+            place = partial(jax.device_put, device=self.w.sharding)
+            # The sampler reads p as a number: a float64 column (held as
+            # bit patterns) is bound from its host copy, like massE.
+            self.p = place(p) if isinstance(p_col, Float64Bits) else p_col
             # Sticky capacities (DESIGN.md §11): recomputed from the new
             # (w, p) but never shrunk below a capacity already traced —
             # a delta that lowers E[k] keeps the cached trace instead of
@@ -154,7 +171,6 @@ class CompiledPlan:
                                     self.policy.sample_capacity(w, p))
             self._arrival_cap = max(self._arrival_cap or 0,
                                     self.policy.arrival_capacity(w, p))
-            place = partial(jax.device_put, device=self.w.sharding)
             self._massE = (place(sampling.exprace_mass(w, p))
                            if self.method == "exprace" else None)
             # Draw-kernel route (probe.select_draw, DESIGN.md §14), decided
@@ -253,7 +269,7 @@ class CompiledPlan:
         # honoring the per-node sampler with it.
         route = "pernode" if rep else self._route
         narrow = self._narrow_call(route, cap, acap)
-        with draw_call(route, cap, acap, 1, 1, narrow):
+        with draw_call(route, cap, acap, 1, 1, narrow, self._bit_columns):
             return self._jit(
                 self.shred, self.w, self.p, self.prefE, key, cap=cap,
                 rep=rep or self.rep_default, n=n, acap=acap,
@@ -287,7 +303,8 @@ class CompiledPlan:
         kpad, _ = executors.pad_batch_keys(keys)
         route = "pernode" if rep else self._route  # explicit rep pins pernode
         narrow = self._narrow_call(route, cap, acap)
-        with draw_call(route, cap, acap, batch, int(kpad.shape[0]), narrow):
+        with draw_call(route, cap, acap, batch, int(kpad.shape[0]), narrow,
+                       self._bit_columns):
             smp = self._batched_jit(
                 self.shred, self.w, self.p, self.prefE, kpad, cap=cap,
                 rep=rep or self.rep_default, n=n, acap=acap,

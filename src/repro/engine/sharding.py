@@ -35,10 +35,11 @@ from repro.core.distributed import (StackedShred, fold_shard_key,
                                     place_stacked)
 from repro.core.jointree import JoinQuery
 from repro.core.poisson import JoinSample
+from repro.core.relations import place_column
 
 from . import executors
 from .capacity import CapacityPolicy, DEFAULT_POLICY
-from .plan import draw_call, redraw_with_doubling, resolve_narrow
+from .plan import bit_columns, draw_call, redraw_with_doubling, resolve_narrow
 from .spec import DrawSpec
 
 __all__ = ["ShardPlan", "ShardedPlan", "plan_shards", "BATCH_AXES"]
@@ -159,6 +160,7 @@ class ShardedPlan:
         self.rep = rep
         self.num_shards = stacked.num_shards
         self.join_sizes = stacked.join_sizes
+        self._bit_columns = bit_columns(stacked.shred, self.project)
         # Global flat offset of each shard's position space: shard flattens
         # concatenate to the global flatten, so shard-local position + base
         # is the same coordinate the single-device plan reports.
@@ -322,7 +324,8 @@ class ShardedPlan:
         st = self.stacked
         cap, acap = cap or self.cap, acap or self.acap
         narrow = self._narrow_call(cap, acap)
-        with draw_call("sharded", cap, acap, 1, self.num_shards, narrow):
+        with draw_call("sharded", cap, acap, 1, self.num_shards, narrow,
+                       self._bit_columns):
             return self._sampler(cap, acap, narrow)(
                 st.shred, st.w, st.p, st.prefE, self.massE, key)
 
@@ -373,7 +376,7 @@ class ShardedPlan:
         for v, a in columns.items():
             buf = np.zeros((out_cap,), a.dtype)
             buf[:rows.size] = a[rows, lanes]
-            cols[v] = jnp.asarray(buf)
+            cols[v] = place_column(buf)
         posbuf = np.zeros((out_cap,), np.int64)
         posbuf[:rows.size] = positions[rows, lanes] + self._bases[rows]
         return JoinSample(
@@ -404,7 +407,8 @@ class ShardedPlan:
         cap, acap = cap or self.cap, acap or self.acap
         narrow = self._narrow_call(cap, acap)
         with draw_call("sharded", cap, acap, batch,
-                       int(kpad.shape[0]) * self.num_shards, narrow):
+                       int(kpad.shape[0]) * self.num_shards, narrow,
+                       self._bit_columns):
             smp, _totals = self._batched_sampler(cap, acap, narrow)(
                 st.shred, st.w, st.p, st.prefE, self.massE, kpad)
         # Host gather per lane (padding lanes never gathered), then stack.
@@ -446,7 +450,7 @@ class ShardedPlan:
         out = {}
         for v, arr in cols.items():
             a = np.asarray(arr)
-            out[v] = jnp.asarray(np.concatenate(
+            out[v] = place_column(np.concatenate(
                 [a[s, :self.join_sizes[s]] for s in range(self.num_shards)]))
         return out
 

@@ -8,7 +8,8 @@
   ``time.perf_counter_ns``.
 * ``count(name, n)`` adds to the open record's counters: each draw
   executor call counts ``draws``, ``draws_int32`` where EXPRACE runs in
-  int32, and ``draw_arrival_lanes``.
+  int32, ``draw_arrival_lanes``, and ``draw_bit_columns`` (the float64
+  columns it gathers as bit patterns, times its draws).
 * Device phases inside jitted code are named with ``jax.named_scope``
   (the names in ``SCOPES``). A scope changes only the op-name metadata of
   the compiled program, never what it computes; a ``TraceAnnotation``
@@ -60,6 +61,7 @@ SCOPES = (
     "exprace_emit",         #   output slots
     "usr_locate",           # core/probe.get_rows: root and child locates
     "usr_gather",           # core/probe.gather_columns
+    "usr_gather_bits",      #   its float64 columns, gathered as bit patterns
     "search",               # kernels/ops searches
 )
 

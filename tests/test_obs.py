@@ -144,11 +144,13 @@ def test_the_draw_executor_carries_every_draw_scope(job_plan):
         dparams=None, massE=plan._massE).compile().as_text()
     assert _scopes_in(text) == {
         "exprace", "exprace_arrivals", "exprace_dedupe", "exprace_compact",
-        "exprace_emit", "usr_locate", "usr_gather", "search"}
+        "exprace_emit", "usr_locate", "usr_gather", "usr_gather_bits",
+        "search"}
     # stages and searches nest under their phase
     names = _op_names(text)
     assert any("/exprace/exprace_dedupe/" in n for n in names)
     assert any("/usr_locate/search/" in n for n in names)
+    assert any("/usr_gather/usr_gather_bits/" in n for n in names)
 
 
 def test_scopes_change_no_result(job_plan, monkeypatch):
@@ -185,9 +187,11 @@ def test_engine_calls_open_their_spans_and_count_draws(job_plan):
             if s.parent is not None] == [("engine_sample", "plan_draw"),
                                          ("engine_sample_batch", "plan_draw")]
     acap = plan.arrival_capacity()
-    # three keys run as a bucket of four lanes; the join certifies int32
+    # three keys run as a bucket of four lanes; the join certifies int32;
+    # p, float64, is carried as bit patterns in each of the four draws
     assert rec.counters == {"draws": 4, "draws_int32": 4,
-                            "draw_arrival_lanes": acap * 5}
+                            "draw_arrival_lanes": acap * 5,
+                            "draw_bit_columns": 4}
 
 
 def test_redraws_count_each_executor_call_at_its_capacity(job_plan):
@@ -201,7 +205,8 @@ def test_redraws_count_each_executor_call_at_its_capacity(job_plan):
     assert not bool(smp.overflow) and len(calls) >= 2
     assert rec.counters == {"draws": len(calls), "draws_int32": len(calls),
                             "draw_arrival_lanes": acap * (2 ** len(calls)
-                                                          - 1)}
+                                                          - 1),
+                            "draw_bit_columns": len(calls)}
 
 
 def test_a_sharded_draw_counts_the_lanes_of_every_shard(job_plan):
@@ -220,7 +225,8 @@ def test_a_sharded_draw_counts_the_lanes_of_every_shard(job_plan):
         "engine_sample", "plan_draw", "engine_sample_batch", "plan_draw"]
     assert rec.counters == {
         "draws": 4, "draws_int32": 4,
-        "draw_arrival_lanes": plan.acap * plan.num_shards * 5}
+        "draw_arrival_lanes": plan.acap * plan.num_shards * 5,
+        "draw_bit_columns": 4}
 
 
 def test_draws_int32_counts_only_narrowed_draws(job_plan):

@@ -136,8 +136,9 @@ def test_declared_unlowerable_kernels_still_fail(one_chip, kernel):
 
 @pytest.fixture(scope="module")
 def draw_step(one_chip):
-    """The engine's jitted single-draw executor compiled for the described
-    chip, at EXPRACE's int32 or int64 width (memoized per width).
+    """The engine's jitted draw executor compiled for the described chip,
+    at EXPRACE's int32 or int64 width, for one draw or (``batch``) a batch
+    of draws (memoized per width and batch).
 
     It runs the route a TPU binds (per-node USR GET, XLA EXPRACE, searches
     as ``ops.kary_searchsorted`` — what ``ops.searchsorted`` picks on a TPU
@@ -158,16 +159,20 @@ def draw_step(one_chip):
     key = _spec((), jax.random.key(0).dtype, one_chip)
     compiled = {}
 
-    def compile_at(narrow):
-        if narrow not in compiled:
+    def compile_at(narrow, batch=None):
+        if (narrow, batch) not in compiled:
+            fn, keys = plan._jit, key
+            if batch is not None:
+                fn = plan._batched_jit
+                keys = _spec((batch,), key.dtype, one_chip)
             with pytest.MonkeyPatch.context() as mp:
                 mp.setattr(ops, "searchsorted", ops.kary_searchsorted)
-                compiled[narrow] = plan._jit.lower(
-                    *args, key, cap=plan.default_capacity(), rep="usr", n=0,
+                compiled[narrow, batch] = fn.lower(
+                    *args, keys, cap=plan.default_capacity(), rep="usr", n=0,
                     acap=plan.arrival_capacity(), narrow=narrow,
                     route="pernode", dparams=None,
                     massE=sds(np.asarray(plan._massE))).compile()
-        return compiled[narrow]
+        return compiled[narrow, batch]
 
     return compile_at
 
@@ -181,3 +186,17 @@ def test_engine_draw_step_compiles(draw_step, narrow):
     assert "slice_sizes={1,128}" in c.as_text()  # the searches' row gathers
     if narrow:
         assert temp < draw_step(False).memory_analysis().temp_size_in_bytes
+
+
+def test_batched_draw_step_returns_no_f64(draw_step):
+    """B=4 beside the single draw: the float64 attribute ``p`` leaves the
+    batched draw as int64 bit patterns, no output is f64, and the batch's
+    scratch fits a v5e's 16 GB."""
+    c = draw_step(True, batch=4)
+    single = draw_step(True)
+    assert c.memory_analysis().temp_size_in_bytes < 16 << 30
+    out = c.out_info
+    assert not [x for x in jax.tree.leaves(out) if x.dtype == jnp.float64]
+    p = out.columns["p"]
+    assert p.bits.dtype == jnp.int64
+    assert p.shape == (4,) + single.out_info.columns["p"].shape
